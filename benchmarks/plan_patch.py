@@ -22,7 +22,9 @@ the build fails.
 A separate 8-host-device subprocess cell replays a move sequence through a
 jitted ``make_bsp_forward`` and records the trace counts: value-only
 patches must compile exactly once overall (zero retraces), the forced
-capacity-growth step exactly once more.
+capacity-growth step exactly once more.  The child runs on the host
+platform (``JAX_PLATFORMS=cpu``) so it never competes for an accelerator,
+and a failed child fails the run.
 
 Usage: PYTHONPATH=src python benchmarks/plan_patch.py [--quick] [--smoke]
 """
@@ -152,7 +154,7 @@ _RETRACE_SUBPROCESS = textwrap.dedent("""
     from repro.gnn import (GNNConfig, init_params, compile_plan, patch_plan,
                            make_bsp_forward, scatter_features)
     from repro.core.partition import partition_from_assign
-    from repro.jaxcompat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     rng = np.random.default_rng(0)
     g = synthetic_siot(n=240, target_links=700)
@@ -183,12 +185,15 @@ _RETRACE_SUBPROCESS = textwrap.dedent("""
 
 
 def run_retrace_cell() -> dict:
-    env = dict(os.environ, PYTHONPATH=os.path.abspath("src"))
+    src = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src")
+    env = dict(os.environ, PYTHONPATH=os.path.normpath(src),
+               JAX_PLATFORMS="cpu")
     env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, "-c", _RETRACE_SUBPROCESS], env=env,
                        capture_output=True, text=True, timeout=900)
     if r.returncode != 0:
-        return {"error": (r.stdout + r.stderr)[-2000:]}
+        raise RuntimeError("retrace cell failed:\n"
+                           + (r.stdout + r.stderr)[-2000:])
     cell = json.loads(r.stdout.strip().splitlines()[-1])
     cell["zero_retrace_on_patch"] = cell["traces_after_patches"] == 1
     cell["single_retrace_on_growth"] = cell["traces_after_growth"] == 2
@@ -214,9 +219,7 @@ def _verify(cells: list, retrace: dict) -> list:
             bad.append(f"n={c['n']} m={c['m']}: patched plan diverged from "
                        f"fresh compile on {c['patch_parity_mismatches']} "
                        f"steps")
-    if "error" in retrace:
-        bad.append(f"retrace cell failed: {retrace['error'][:300]}")
-    elif not (retrace.get("zero_retrace_on_patch")
+    if not (retrace.get("zero_retrace_on_patch")
               and retrace.get("single_retrace_on_growth")):
         bad.append(f"retrace counts off: {retrace}")
     return bad

@@ -22,6 +22,7 @@ import argparse
 import sys
 import time
 
+from repro import compile_cache
 from benchmarks import (adaptability, convergence, cost_comparison,
                         cost_factors, kernel_density, layout_engine,
                         overhead, plan_patch, roofline_table, sensitivity,
@@ -83,4 +84,5 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

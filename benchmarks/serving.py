@@ -19,9 +19,9 @@ end-to-end on a yelp-shaped graph:
     knowing the traffic improve the layout it serves from?  Gate:
     aware <= blind.
   * every cell replays a sample of served targets through the whole-graph
-    oracle ``models.forward`` and counts exact float mismatches — the GCN
-    ego forward is BIT-exact vs the oracle (see tests/test_serving.py for
-    why gat/sage sit ~1 ulp off), so the gate is 0 mismatches.
+    reference ``models.reference_forward`` and counts rows outside the f32
+    reduction-order tolerance (``F32_REORDER``; see
+    tests/test_serving.py), so the gate is 0 mismatches.
 
 Section ``replication_cells`` — the move-vs-replicate A/B
 (:func:`run_replication_cell`): the same stream priced on the blind, the
@@ -47,20 +47,30 @@ import sys
 import time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core.cost import CostModel, workload_for
 from repro.core.glad_s import glad_s
 from repro.core.partition import partition_from_assign
 from repro.gnn.distributed import (compile_plan, patch_plan, plans_equal,
                                    recompile_like)
-from repro.gnn.models import GNNConfig, directed_edges, forward, init_params
+from repro.gnn.models import GNNConfig, init_params, reference_forward
 from repro.gnn.serving import (GNNServeEngine, link_traffic,
                                replicate_for_stream, request_traffic,
                                serving_cost, zipf_requests)
 from repro.graphs.datagraph import synthetic_siot, synthetic_yelp
 from repro.graphs.edgenet import build_edge_network
+
+
+# Served rows agree with the whole-graph reference up to f32 summation order
+# (the ego table's matmuls have another height than the whole graph's).
+F32_REORDER = dict(rtol=1e-5, atol=1e-6)
+
+
+def _mismatches(out: np.ndarray, ref: np.ndarray) -> int:
+    """Rows of ``out`` outside the f32 reduction-order tolerance."""
+    return int((~np.isclose(out, ref, **F32_REORDER)).any(axis=1).sum())
 
 
 def _layouts(cm_blind, cm_aware, parts: int, seed: int):
@@ -111,12 +121,10 @@ def run_serving_cell(n: int, parts: int, requests: int, seed: int = 0,
     lat = eng.latency_percentiles()
     cache = eng.cache_stats()
 
-    # Exact-parity replay: served outputs vs the whole-graph oracle.
-    oracle = np.asarray(forward(cfg, params, jnp.asarray(g.features),
-                                jnp.asarray(directed_edges(g.edges))))
+    # Parity replay: served outputs vs the whole-graph reference.
+    oracle = reference_forward(cfg, params, g.features, g.edges)
     sample = np.unique(stream[:take])[:parity_sample]
-    out = eng.serve(sample)
-    mismatches = int((out != oracle[sample]).any(axis=1).sum())
+    mismatches = _mismatches(eng.serve(sample), oracle[sample])
 
     s = eng.stats
     return {
@@ -207,12 +215,10 @@ def run_replication_cell(kind: str, n: int, parts: int, requests: int,
         eng_repl.tick()
 
     # Oracle parity on the replicated engine: replicas change where rows
-    # are READ from, never the values — served outputs stay exact.
-    oracle = np.asarray(forward(cfg, params, jnp.asarray(g.features),
-                                jnp.asarray(directed_edges(g.edges))))
+    # are READ from, never the values.
+    oracle = reference_forward(cfg, params, g.features, g.edges)
     sample = np.unique(stream[:take])[:parity_sample]
-    out = eng_repl.serve(sample)
-    mismatches = int((out != oracle[sample]).any(axis=1).sum())
+    mismatches = _mismatches(eng_repl.serve(sample), oracle[sample])
 
     # Replica patch-stability through a live move sweep.
     rng = np.random.default_rng(seed + 7)
@@ -357,7 +363,7 @@ def main(argv=None) -> int:
             for b in bad:
                 print("  " + b)
             return 1
-        print("serving gate: oracle parity exact, traffic-aware layout "
+        print("serving gate: reference parity held, traffic-aware layout "
               "serves cheaper, replication beats move-only")
     return 0
 
@@ -427,4 +433,5 @@ def run(full: bool = False, smoke: bool = False) -> int:
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     sys.exit(main())

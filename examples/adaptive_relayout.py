@@ -10,6 +10,7 @@ import argparse
 
 import numpy as np
 
+from repro import compile_cache
 from repro.core import GladA, workload_for
 from repro.core.evolution import apply_delta, evolution_trace
 from repro.core.partition import partition_from_assign
@@ -79,4 +80,5 @@ if __name__ == "__main__":
     ap.add_argument("--slots", type=int, default=30)
     ap.add_argument("--theta", type=float, default=10.0)
     a = ap.parse_args()
+    compile_cache.enable()
     main(a.slots, a.theta)

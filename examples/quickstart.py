@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import (CostModel, glad_s, greedy_layout, random_layout,
                         workload_for)
 from repro.core.partition import partition_from_assign
@@ -50,4 +51,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

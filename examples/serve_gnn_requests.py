@@ -14,6 +14,7 @@ import dataclasses
 import jax
 import numpy as np
 
+from repro import compile_cache
 from repro.core import CostModel, workload_for
 from repro.core.glad_s import glad_s
 from repro.core.partition import partition_from_assign
@@ -87,4 +88,5 @@ if __name__ == "__main__":
     ap.add_argument("--requests", type=int, default=2000)
     ap.add_argument("--servers", type=int, default=6)
     a = ap.parse_args()
+    compile_cache.enable()
     main(a.requests, a.servers)

@@ -10,6 +10,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro import models as zoo
 from repro.configs import get_smoke_config
 from repro.serve import Request, ServeEngine
@@ -37,4 +38,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -10,6 +10,7 @@ import tempfile
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.core import data_partition, workload_for
 from repro.gnn import GNNConfig, directed_edges, init_params
 from repro.gnn.training import accuracy, train_step
@@ -77,4 +78,6 @@ def main(steps: int = 300):
 if __name__ == "__main__":
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
-    main(ap.parse_args().steps)
+    steps = ap.parse_args().steps
+    compile_cache.enable()
+    main(steps)

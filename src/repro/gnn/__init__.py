@@ -1,6 +1,6 @@
 from repro.gnn.models import (
     GNNConfig, directed_edges, forward, init_params, loss_fn, predict,
-    segment_sum,
+    reference_forward, segment_sum,
 )
 from repro.gnn.distributed import (
     PlanBSR, PlanCaps, PlanDelta, ShardPlan, build_plan_bsr, compile_plan,
@@ -16,7 +16,7 @@ from repro.gnn.serving import (
 
 __all__ = [
     "GNNConfig", "directed_edges", "forward", "init_params", "loss_fn",
-    "predict", "segment_sum",
+    "predict", "reference_forward", "segment_sum",
     "PlanBSR", "PlanCaps", "PlanDelta", "ShardPlan", "build_plan_bsr",
     "compile_plan", "gather_outputs", "make_bsp_forward", "patch_plan",
     "plan_caps", "plans_equal", "recompile_like", "scatter_features",

@@ -53,9 +53,8 @@ from typing import Optional, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import jaxcompat
 from repro.core.partition import DevicePartition, halos_of
 from repro.gnn.models import GNNConfig, segment_sum
 from repro.graphs.datagraph import DataGraph
@@ -1251,7 +1250,10 @@ def make_bsp_forward(
     up with ZERO retraces; capacity growth or a new ppermute round changes
     the operand signature and recompiles exactly once.  ``fwd.stats``
     exposes ``{'traces': ..., 'builds': ...}`` for the retrace-count
-    assertions in tests and benchmarks.
+    assertions in tests and benchmarks.  ``fwd.lower(params, blocks)``
+    lowers the current program without running it; the plan's operands
+    enter as shapes on ``mesh``, so it also lowers for a described, not
+    attached, topology.
 
     ``exchange='ppermute'`` moves only cut-link rows (GLAD-aware);
     ``'allgather'`` is the layout-agnostic baseline.  ``aggregate`` picks
@@ -1262,6 +1264,7 @@ def make_bsp_forward(
         build_plan_bsr(plan)
     impl = "pallas" if _on_tpu() else "jnp"
     spec_b = P(axis_name)
+    sharded = NamedSharding(mesh, spec_b)
     state = {"sig": None, "fn": None, "version": -1, "ops": None,
              "traces": 0, "builds": 0}
 
@@ -1286,6 +1289,7 @@ def make_bsp_forward(
         return sig
 
     def _operands():
+        """The plan's per-device tables, (P, ...) each, in operand order."""
         ops = [plan.edges_src, plan.edges_dst, plan.deg, plan.halo_slot]
         if mode == "pallas":
             ops += [plan.bsr.values, plan.bsr.block_cols]
@@ -1295,7 +1299,7 @@ def make_bsp_forward(
             if _use_replicas():
                 for r in plan.rounds0:
                     ops += [r["send_idx"], r["recv_pos"]]
-        return tuple(jnp.asarray(a) for a in ops)
+        return ops
 
     def _build():
         shifts = tuple(r["shift"] for r in plan.rounds)
@@ -1341,33 +1345,47 @@ def make_bsp_forward(
 
         n_ops = n_fixed + 2 * n_rounds * (2 if has_repl else 1)
         n_lead = 1 if has_repl else 0
-        smapped = jaxcompat.shard_map(
+        smapped = jax.shard_map(
             inner, mesh=mesh,
             in_specs=(P(), spec_b) + (spec_b,) * (n_lead + n_ops),
             out_specs=spec_b)
         return jax.jit(smapped)
 
-    def forward(params, blocks, replica0=None):
+    def _fn():
         sig = _signature()
         if sig != state["sig"]:
             state["fn"] = _build()
             state["sig"] = sig
             state["builds"] += 1
             state["version"] = -1        # force operand refresh
+        return state["fn"]
+
+    def _lead(replica0):
+        if not _use_replicas():
+            return ()
+        if replica0 is None:
+            raise ValueError(
+                "plan has replicas: pass replica0="
+                "scatter_replica_halo(plan, features) so layer 0 can "
+                "serve replica-resident halo slots locally")
+        return (replica0,)
+
+    def forward(params, blocks, replica0=None):
+        fn = _fn()
         if state["version"] != plan.version:
-            state["ops"] = _operands()
+            # One block per device, placed once per plan version.
+            state["ops"] = tuple(jax.device_put(a, sharded)
+                                 for a in _operands())
             state["version"] = plan.version
-        if _use_replicas():
-            if replica0 is None:
-                raise ValueError(
-                    "plan has replicas: pass replica0="
-                    "scatter_replica_halo(plan, features) so layer 0 can "
-                    "serve replica-resident halo slots locally")
-            return state["fn"](params, blocks, jnp.asarray(replica0),
-                               *state["ops"])
-        return state["fn"](params, blocks, *state["ops"])
+        return fn(params, blocks, *_lead(replica0), *state["ops"])
+
+    def lower(params, blocks, replica0=None):
+        ops = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharded)
+                    for a in _operands())
+        return _fn().lower(params, blocks, *_lead(replica0), *ops)
 
     forward.stats = state
+    forward.lower = lower
     forward.plan = plan
     return forward
 

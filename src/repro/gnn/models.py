@@ -148,6 +148,19 @@ def forward(
     return h
 
 
+def reference_forward(cfg: GNNConfig, params, features: np.ndarray,
+                      edges: np.ndarray) -> np.ndarray:
+    """The plain reference: :func:`forward` over the whole graph (undirected
+    ``edges``) with every matmul at ``highest`` precision.  Served and
+    sharded outputs are compared against it within a tolerance that states
+    its reason; on TPU the default precision rounds matmul operands to
+    bf16, which this reference does not."""
+    with jax.default_matmul_precision("highest"):
+        out = forward(cfg, params, jnp.asarray(features),
+                      jnp.asarray(directed_edges(edges)))
+    return np.asarray(out)
+
+
 def loss_fn(cfg: GNNConfig, params, features, src_dst, labels, mask=None,
             aggregate: Aggregate = segment_sum):
     """Node-classification cross entropy (the paper's SIoT/Yelp tasks)."""

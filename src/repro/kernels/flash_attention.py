@@ -22,7 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import jaxcompat
 
 _NEG_INF = -1e30
 
@@ -135,7 +134,7 @@ def flash_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, lq_pad, D), q.dtype),
-        compiler_params=jaxcompat.pallas_tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary"),
         ),

@@ -33,8 +33,6 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import jaxcompat
-
 
 def _kernel(block_cols_ref, vals_ref, feats_ref, out_ref):
     j = pl.program_id(1)
@@ -85,8 +83,11 @@ def spmm(values, block_cols, feats, *, bm: int, bk: int, bd: int = 128,
             ],
             out_specs=pl.BlockSpec((bm, bd), out_map),
         ),
-        out_shape=jax.ShapeDtypeStruct((n_rows_out, d), feats.dtype),
-        compiler_params=jaxcompat.pallas_tpu_compiler_params(
+        # Inside shard_map (check_vma=True) the output varies over the same
+        # mesh axes as the per-device feature table; outside it is empty.
+        out_shape=jax.ShapeDtypeStruct((n_rows_out, d), feats.dtype,
+                                       vma=jax.typeof(feats).vma),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "parallel"),
         ),
         interpret=interpret,

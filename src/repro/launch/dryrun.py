@@ -23,7 +23,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro import jaxcompat
 from repro import models as zoo
 from repro.configs import (ARCHS, get_config, input_specs, skip_reason)
 from repro.launch.hlo import model_flops_for, roofline
@@ -34,6 +33,11 @@ from repro.models.common import SHAPES
 from repro.models.transformer import Dist
 from repro.train import optim
 from repro.train.step import make_train_step
+
+
+# The production meshes are v5e pods (16x16 = 256 chips); the dry run
+# compiles on host devices, so the roofline names its target chip.
+TARGET_DEVICE_KIND = "TPU v5 lite"
 
 
 def build_dist(mesh, cfg, shape) -> Dist:
@@ -136,7 +140,7 @@ def measure_probe(cfg, arch, shape_name, multi_pod):
     _, _, mesh, lowered = lower_cell(arch, shape_name, multi_pod, cfg=cfg,
                                      microbatches=1)
     compiled = lowered.compile()
-    ca = jaxcompat.cost_analysis(compiled)
+    ca = compiled.cost_analysis()
     colls = parse_collectives(compiled.as_text(), default_group=mesh.size)
     per_kind = {}
     for c in colls:
@@ -175,18 +179,17 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool, outdir: str,
         compiled = lowered.compile()
         t_compile = time.time() - t0 - t_lower
         mem = compiled.memory_analysis()
-        cost = jaxcompat.cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         hlo = compiled.as_text()
-        rf = roofline(compiled, mesh.size,
+        rf = roofline(compiled, mesh.size, TARGET_DEVICE_KIND,
                       model_flops_for(cfg, shape), cost, hlo)
         if exact:
             # Correct the scan-body-counted-once undercount (analysis.py).
             cc = corrected_cost(arch, shape_name, multi_pod, mesh.size)
             from repro.launch import hlo as H
             cbytes = sum(cc["collectives"].values())
-            terms = {"compute": cc["flops"] / H.PEAK_FLOPS,
-                     "memory": cc["bytes"] / H.HBM_BW,
-                     "collective": cbytes / H.ICI_BW}
+            terms = H.roofline_terms(cc["flops"], cc["bytes"], cbytes,
+                                     TARGET_DEVICE_KIND)
             mf = model_flops_for(cfg, shape)
             rf = H.Roofline(
                 flops_per_device=cc["flops"],

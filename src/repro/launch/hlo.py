@@ -11,7 +11,8 @@ Per-device wire-bytes model (ring algorithms, group size N):
   all-to-all        (N-1)/N x buffer
   collective-permute  1 x buffer
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link.
+Hardware peaks come from :data:`PEAKS`, keyed by the ``device_kind`` JAX
+reports; a kind that is not in the table is an error, never a default.
 """
 from __future__ import annotations
 
@@ -19,9 +20,30 @@ import dataclasses
 import re
 from typing import Dict, List, Optional
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+@dataclasses.dataclass(frozen=True)
+class Peaks:
+    flops: float                 # bf16 FLOP/s per chip
+    hbm_bw: float                # HBM bytes/s per chip
+    ici_bw: float                # interconnect bytes/s per link
+
+
+# Source: Google Cloud documentation, "TPU v5e" (system architecture table):
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip over
+# four links (~50 GB/s per link).
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """Published peaks of one chip of ``device_kind`` (``jax.Device.device_kind``)."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(
+            f"no published peaks for device_kind {device_kind!r}; add it to "
+            f"repro.launch.hlo.PEAKS with its source") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s4": 1, "u4": 1, "s8": 1, "u8": 1, "f8e4m3fn": 1, "f8e5m2": 1,
@@ -114,10 +136,18 @@ class Roofline:
         return dataclasses.asdict(self)
 
 
-def roofline(compiled, mesh_devices: int, model_flops: float = 0.0,
-             cost: Optional[dict] = None, hlo: Optional[str] = None) -> Roofline:
-    from repro.jaxcompat import cost_analysis
-    ca = cost or cost_analysis(compiled)
+def roofline_terms(flops: float, hbm_bytes: float, collective_bytes: float,
+                   device_kind: str) -> Dict[str, float]:
+    """Seconds each roofline term takes at ``device_kind``'s peaks."""
+    pk = peaks_for(device_kind)
+    return {"compute": flops / pk.flops, "memory": hbm_bytes / pk.hbm_bw,
+            "collective": collective_bytes / pk.ici_bw}
+
+
+def roofline(compiled, mesh_devices: int, device_kind: str,
+             model_flops: float = 0.0, cost: Optional[dict] = None,
+             hlo: Optional[str] = None) -> Roofline:
+    ca = cost or compiled.cost_analysis()
     flops = float(ca.get("flops", 0.0))
     hbm = float(ca.get("bytes accessed", 0.0))
     text = hlo if hlo is not None else compiled.as_text()
@@ -126,11 +156,7 @@ def roofline(compiled, mesh_devices: int, model_flops: float = 0.0,
     per_kind: Dict[str, float] = {}
     for c in colls:
         per_kind[c.kind] = per_kind.get(c.kind, 0.0) + c.wire_bytes
-    terms = {
-        "compute": flops / PEAK_FLOPS,
-        "memory": hbm / HBM_BW,
-        "collective": cbytes / ICI_BW,
-    }
+    terms = roofline_terms(flops, hbm, cbytes, device_kind)
     bott = max(terms, key=terms.get)
     useful = (model_flops / (flops * mesh_devices)
               if flops > 0 and model_flops else 0.0)

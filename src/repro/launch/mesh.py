@@ -1,14 +1,21 @@
-"""Production mesh builders.  Functions, not module constants — importing
-this module never touches jax device state (smoke tests keep 1 device).
-
-Mesh construction goes through repro.jaxcompat so the same code runs on
-JAX versions with and without ``jax.sharding.AxisType``.
+"""Mesh builders.  Functions, not module constants — importing this module
+never touches jax device state (smoke tests keep 1 device).
 """
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.jaxcompat import make_mesh
+
+def make_mesh(axis_shapes, axis_names, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``.
+
+    ``jax.make_mesh`` defaults to ``Explicit`` axes; every caller here wants
+    the compiler to place what ``shard_map`` and the in/out specs leave
+    open.  ``devices`` defaults to ``jax.devices()``."""
+    return jax.make_mesh(axis_shapes, axis_names,
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):

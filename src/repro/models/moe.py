@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import jaxcompat
 from repro.models.common import LMConfig
 
 
@@ -66,32 +65,13 @@ def _gg_fwd(x, w, gs):
 def _gg_bwd(res, dy):
     x, w, gs = res
     dx = jax.lax.ragged_dot(dy, jnp.swapaxes(w, 1, 2), gs)
-    if jaxcompat.has_ragged_dot_general():
-        dn = jax.lax.RaggedDotDimensionNumbers(
-            dot_dimension_numbers=(((0,), (0,)), ((), ())),
-            lhs_ragged_dimensions=[0],
-            rhs_group_dimensions=[],
-        )
-        dw = jax.lax.ragged_dot_general(
-            x, dy, gs, dn, preferred_element_type=w.dtype)
-    else:
-        # Legacy-JAX fallback: per-group masked GEMM dW[g] = (x*1[gid=g])^T
-        # dy, sequentially over groups (lax.map) so peak memory stays
-        # O(m*k + k*n) — never the (m, k, n) per-token outer-product tensor.
-        # FLOPs are E_local * forward (the dense-adjoint behavior old JAX
-        # had anyway); new JAX takes the ragged_dot_general branch above.
-        # Rows past sum(gs) get gid == E_local -> masked out everywhere,
-        # matching ragged_dot's zero contribution for out-of-group rows.
-        gid = jnp.searchsorted(jnp.cumsum(gs), jnp.arange(x.shape[0]),
-                               side="right")
-        xf = x.astype(jnp.float32)
-        dyf = dy.astype(jnp.float32)
-
-        def _one_group(g):
-            sel = (gid == g).astype(jnp.float32)
-            return (xf * sel[:, None]).T @ dyf
-
-        dw = jax.lax.map(_one_group, jnp.arange(w.shape[0]))
+    dn = jax.lax.RaggedDotDimensionNumbers(
+        dot_dimension_numbers=(((0,), (0,)), ((), ())),
+        lhs_ragged_dimensions=[0],
+        rhs_group_dimensions=[],
+    )
+    dw = jax.lax.ragged_dot_general(
+        x, dy, gs, dn, preferred_element_type=w.dtype)
     return dx.astype(x.dtype), dw.astype(w.dtype), None
 
 
@@ -174,7 +154,7 @@ def moe_ffn(
 
     fs = fsdp_axes if len(fsdp_axes) > 1 else fsdp_axes[0]
     bspec = P(batch_axes, None, None)
-    out = jaxcompat.shard_map(
+    out = jax.shard_map(
         body, mesh=mesh,
         in_specs=(bspec, P(batch_axes, None, None), P(batch_axes, None, None),
                   P(model_axis, fs, None),

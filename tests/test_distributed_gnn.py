@@ -83,7 +83,7 @@ _SUBPROCESS = textwrap.dedent("""
     assign = np.random.default_rng(0).integers(0, 4, size=g.n)
     part = partition_from_assign(g, assign, 4, {})
     plan = compile_plan(g, part)
-    from repro.jaxcompat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((4,), ('data',))
     blocks = jnp.asarray(scatter_features(plan, g.features))
     sd = jnp.asarray(directed_edges(g.edges))

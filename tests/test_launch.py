@@ -7,8 +7,15 @@ from jax.sharding import PartitionSpec as P
 from repro.configs import (ARCHS, all_cells, applicable_shapes, get_config,
                            input_specs)
 from repro.launch.hlo import (_shape_bytes, model_flops_for,
-                              parse_collectives, _wire_bytes)
+                              parse_collectives, roofline_terms, _wire_bytes)
 from repro.models.common import SHAPES
+
+
+def test_roofline_peaks_keyed_by_device_kind():
+    t = roofline_terms(197e12, 819e9, 50e9, "TPU v5 lite")
+    assert t == {"compute": 1.0, "memory": 1.0, "collective": 1.0}
+    with pytest.raises(ValueError, match="device_kind"):
+        roofline_terms(1.0, 1.0, 1.0, "cpu")
 
 
 def test_shape_bytes():

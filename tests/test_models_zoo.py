@@ -136,7 +136,7 @@ def test_moe_sharded_equals_dense_ref_subprocess_free():
          "w13": jax.random.normal(k[1], (4, 16, 16)) * 0.1,
          "w2": jax.random.normal(k[2], (4, 8, 16)) * 0.1}
     x = jax.random.normal(k[3], (2, 6, 16))
-    from repro.jaxcompat import make_mesh
+    from repro.launch.mesh import make_mesh
     mesh = make_mesh((1, 1), ("data", "model"))
     ref, _ = moe_ffn_dense_ref(cfg, p, x)
     out, _ = jax.jit(lambda p, x: moe_ffn(cfg, p, x, mesh, ("data",)))(p, x)

@@ -5,7 +5,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.jaxcompat import make_mesh
+from repro.launch.mesh import make_mesh
 from repro.models.common import LMConfig, sharded_ce_loss
 from repro.models.moe import grouped_gemm, moe_ffn, moe_ffn_dense_ref, router_topk
 

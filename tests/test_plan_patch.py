@@ -328,7 +328,7 @@ _PARITY_SUBPROCESS = textwrap.dedent("""
                            compile_plan, make_bsp_forward, scatter_features,
                            gather_outputs, simulate_bsp_forward)
     from repro.core.partition import partition_from_assign
-    from repro.jaxcompat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     g = synthetic_siot(n=160, target_links=420)
     assign = np.random.default_rng(0).integers(0, 8, size=g.n)
@@ -363,7 +363,7 @@ _PATCH_SUBPROCESS = textwrap.dedent("""
                            recompile_like, plans_equal, make_bsp_forward,
                            scatter_features, gather_outputs)
     from repro.core.partition import partition_from_assign
-    from repro.jaxcompat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     rng = np.random.default_rng(0)
     g = synthetic_siot(n=240, target_links=700)

@@ -212,7 +212,7 @@ _REPL_FWD_SUBPROCESS = textwrap.dedent("""
     from repro.gnn import (GNNConfig, init_params, compile_plan,
                            make_bsp_forward, scatter_features,
                            scatter_replica_halo, gather_outputs)
-    from repro.jaxcompat import make_mesh
+    from repro.launch.mesh import make_mesh
 
     g = synthetic_siot(n=160, target_links=420)
     assign = np.random.default_rng(0).integers(0, 8, size=g.n)

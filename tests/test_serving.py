@@ -1,6 +1,6 @@
 """Request-driven serving: ego extraction parity vs a dense BFS oracle,
-ego-forward bit-match vs the whole-graph forward, cache admission, and the
-live-plan serving loop (including a mid-stream plan patch)."""
+ego-forward agreement with the whole-graph reference, cache admission, and
+the live-plan serving loop (including a mid-stream plan patch)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -9,12 +9,18 @@ import pytest
 from repro.core.partition import partition_from_assign
 from repro.gnn.distributed import compile_plan, patch_plan
 from repro.gnn.models import (GNNConfig, directed_edges, forward,
-                              init_params)
+                              init_params, reference_forward)
 from repro.gnn.serving import (FeatureCache, GNNServeEngine, ego_tables,
                                extract_ego, extract_ego_batch, link_traffic,
                                make_ego_forward, request_traffic,
                                serving_cost, zipf_requests)
 from tests.conftest import random_graph
+
+# The ego forward sums in f32 in another order than the whole-graph
+# reference: its matmuls have another height, and XLA tiles (and so rounds)
+# a dot by its shape.  That moves a target row by a few ulp, so agreement is
+# an f32 reduction-order tolerance, on every backend, and not bit equality.
+F32_REORDER = dict(rtol=1e-5, atol=1e-6)
 
 
 # ------------------------------------------------------------------ extraction
@@ -50,7 +56,7 @@ def test_extract_ego_matches_dense_bfs(seed):
 
     # Arcs: ALL incoming arcs of every node at depth < hops, none for the
     # depth-`hops` rim, each dst's srcs in ascending order (the summation
-    # order that makes the forward bit-match the oracle).
+    # order of the whole-graph forward).
     inner = nodes[depth < hops]
     adj = {}
     for u, v in g.edges:
@@ -100,15 +106,13 @@ def test_extract_ego_batch_padding_invariants(small_siot):
 # ----------------------------------------------------------------- ego forward
 @pytest.mark.parametrize("jit", [True, False])
 def test_ego_forward_gcn_bitmatches_oracle(jit, small_siot):
-    """With full fanout the GCN ego forward is BIT-exact vs the whole-graph
-    forward at the target rows, jitted or eager: its only reductions are
-    segment sums (order preserved by extraction) and matmuls whose per-row
-    bits are M-independent on XLA CPU."""
+    """With full fanout the GCN ego forward reproduces the whole-graph
+    reference at the target rows, jitted or eager, within f32
+    reduction-order tolerance (``F32_REORDER``)."""
     g = small_siot
     cfg = GNNConfig("gcn", (g.features.shape[1], 16, 4))
     params = init_params(jax.random.PRNGKey(0), cfg)
-    oracle = np.asarray(forward(cfg, params, jnp.asarray(g.features),
-                                jnp.asarray(directed_edges(g.edges))))
+    oracle = reference_forward(cfg, params, g.features, g.edges)
     targets = np.array([0, 7, 31, 149, 80])
     ego = extract_ego_batch(g, targets, hops=cfg.num_layers, batch=8)
     feats, deg, tgt = ego_tables(ego, g.features,
@@ -116,7 +120,8 @@ def test_ego_forward_gcn_bitmatches_oracle(jit, small_siot):
     fwd = make_ego_forward(cfg, params, jit=jit)
     out = np.asarray(fwd(jnp.asarray(feats), jnp.asarray(ego.arcs),
                          jnp.asarray(deg), jnp.asarray(tgt)))
-    np.testing.assert_array_equal(out[: len(targets)], oracle[targets])
+    np.testing.assert_allclose(out[: len(targets)], oracle[targets],
+                               **F32_REORDER)
 
 
 def test_ego_forward_sage_eager_exact_jit_one_ulp(small_siot):
@@ -138,7 +143,7 @@ def test_ego_forward_sage_eager_exact_jit_one_ulp(small_siot):
     np.testing.assert_array_equal(eager[: len(targets)], oracle[targets])
     jitted = np.asarray(make_ego_forward(cfg, params)(*args))
     np.testing.assert_allclose(jitted[: len(targets)], oracle[targets],
-                               rtol=1e-5, atol=1e-6)
+                               **F32_REORDER)
 
 
 @pytest.mark.parametrize("jit", [True, False])
@@ -159,7 +164,7 @@ def test_ego_forward_gat_within_ulp(jit, small_siot):
     out = np.asarray(fwd(jnp.asarray(feats), jnp.asarray(ego.arcs),
                          jnp.asarray(deg), jnp.asarray(tgt)))
     np.testing.assert_allclose(out[: len(targets)], oracle[targets],
-                               rtol=1e-5, atol=1e-6)
+                               **F32_REORDER)
 
 
 def test_ego_forward_retrace_bound(small_siot):
@@ -285,9 +290,8 @@ def test_engine_serves_oracle_outputs(served_cluster):
     eng = GNNServeEngine(cfg, params, g, plan, batch=4)
     targets = zipf_requests(g.n, 17, seed=2)
     out = eng.serve(targets)
-    oracle = np.asarray(forward(cfg, params, jnp.asarray(g.features),
-                                jnp.asarray(directed_edges(g.edges))))
-    np.testing.assert_array_equal(out, oracle[targets])
+    oracle = reference_forward(cfg, params, g.features, g.edges)
+    np.testing.assert_allclose(out, oracle[targets], **F32_REORDER)
     assert eng.stats.requests == 17
     assert eng.stats.batches == 5                       # ceil(17/4)
     assert eng.stats.local_rows + eng.stats.cache_hit_rows \
@@ -299,20 +303,21 @@ def test_engine_serves_oracle_outputs(served_cluster):
 
 def test_engine_survives_plan_patch_mid_stream(served_cluster):
     """The fault-runtime handoff: patch_plan moves vertices mid-stream; the
-    engine re-seeds caches off the new halos and keeps answering with
-    oracle-exact outputs."""
+    engine re-seeds caches off the new halos and keeps answering with the
+    reference's outputs."""
     g, cfg, params, plan = served_cluster
     eng = GNNServeEngine(cfg, params, g, plan, batch=4)
-    oracle = np.asarray(forward(cfg, params, jnp.asarray(g.features),
-                                jnp.asarray(directed_edges(g.edges))))
+    oracle = reference_forward(cfg, params, g.features, g.edges)
     first = np.array([0, 1, 2, 3])
-    np.testing.assert_array_equal(eng.serve(first), oracle[first])
+    np.testing.assert_allclose(eng.serve(first), oracle[first],
+                               **F32_REORDER)
 
     new_assign = plan.assign.copy()
     new_assign[:30] = (new_assign[:30] + 1) % 4        # relayout delta
     patch_plan(plan, g, new_assign)
     second = np.array([5, 8, 13, 21])
-    np.testing.assert_array_equal(eng.serve(second), oracle[second])
+    np.testing.assert_allclose(eng.serve(second), oracle[second],
+                               **F32_REORDER)
     assert eng.stats.plan_refreshes == 1
     cs = eng.cache_stats()
     assert cs["resident"] >= 0 and cs["hits"] + cs["misses"] >= 0
