@@ -1,0 +1,8 @@
+"""The chip benchmark's yardstick: inputs, traffic, reference, work counts,
+peaks and the reduction from traces to metrics.
+
+Nothing here imports the program except ``fleet`` and the drivers
+(``ego``, ``refresh``), which build and drive the system under test.
+Everything a later PR must not be able to change (what is generated, what
+is compared, how work and time are counted) lives in this package.
+"""

@@ -1,0 +1,61 @@
+"""Set-up shared by every driver: the configuration's graph, the run's
+features and weights, and the program's layout of the graph over the
+fleet (``build_edge_network`` -> GLAD-S -> ``compile_plan``)."""
+from __future__ import annotations
+
+import dataclasses
+
+import numpy as np
+
+from harness import graphs, inputs
+
+
+@dataclasses.dataclass
+class Deployment:
+    n: int
+    edges: np.ndarray          # (E, 2) canonical links
+    feats_dev: object          # (n, d_0) on the first device
+    feats: np.ndarray          # the same on the host
+    weights: list              # [{"w": ...}] on the first device
+    graph: object              # the program's DataGraph
+    model: object              # the program's GNNConfig
+
+
+def build(config: dict, seed: int, device) -> Deployment:
+    from repro.gnn import GNNConfig
+    from repro.graphs.datagraph import DataGraph
+
+    n, edges, coords = graphs.build(config["graph"])
+    feats_dev, weights = inputs.make(config["model"], n, seed, device)
+    feats = np.asarray(feats_dev)
+    g = DataGraph(n=n, edges=edges, features=feats, coords=coords)
+    m = config["model"]
+    return Deployment(n=n, edges=edges, feats_dev=feats_dev, feats=feats,
+                      weights=weights, graph=g,
+                      model=GNNConfig(m["kind"], tuple(m["layer_dims"])))
+
+
+def layout(config: dict, dep: Deployment, parts: int):
+    """(EdgeNetwork, ShardPlan).  ``parts == 1`` keeps every vertex on one
+    partition, as a single server holds the whole graph."""
+    from repro.core import CostModel, workload_for
+    from repro.core.glad_s import glad_s
+    from repro.core.partition import partition_from_assign
+    from repro.gnn import compile_plan
+    from repro.graphs import build_edge_network
+
+    fl, g = config["fleet"], dep.graph
+    seed = int(config["graph"]["seed"])
+    net = build_edge_network(g, int(fl["servers"]), seed=seed,
+                             mu_factor=float(fl["mu_factor"]))
+    if parts == 1:
+        part = partition_from_assign(g, np.zeros(g.n, np.int64), 1, {})
+    else:
+        if parts != int(fl["servers"]):
+            raise ValueError(f"{parts} partitions over "
+                             f"{fl['servers']} servers")
+        cm = CostModel(net, g, workload_for(config["model"]["kind"],
+                                            g.features.shape[1]))
+        res = glad_s(cm, seed=seed)
+        part = partition_from_assign(g, res.assign, parts, res.factors)
+    return net, compile_plan(g, part, slack=float(fl["slack"]))

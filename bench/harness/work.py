@@ -1,0 +1,48 @@
+"""Useful work, counted from the graph's own sizes.
+
+Never from the plan's padded capacities or from the BSR tiling: a PR that
+retiles or replaces the aggregation kernel changes its time, not these
+counts.  ``arcs`` is the number of directed arcs (twice the links); ``n``
+the vertices.
+
+Aggregation of one layer at width ``d`` (the neighbour sum): ``arcs * d``
+additions; bytes are every table row read once, every output row written
+once, and the two int32 indices of each arc.  Dense layers: ``2 n d_in
+d_out`` (``2 d_in`` for SAGE's concatenation).  GCN also adds its own row
+and divides by the degree (``2 n d_in``); SAGE divides by the degree
+(``n d_in``).
+"""
+from __future__ import annotations
+
+F32 = 4
+INDEX = 4
+
+
+def aggregation(n: int, arcs: int, d: int) -> tuple[int, int]:
+    """(flops, bytes) of one neighbour sum over the whole graph."""
+    flops = arcs * d
+    nbytes = 2 * n * d * F32 + 2 * arcs * INDEX
+    return flops, nbytes
+
+
+def model_flops(kind: str, layer_dims, n: int, arcs: int) -> int:
+    """Operations of one whole-graph forward."""
+    total = 0
+    for d_in, d_out in zip(layer_dims[:-1], layer_dims[1:]):
+        total += arcs * d_in
+        if kind == "gcn":
+            total += 2 * n * d_in + 2 * n * d_in * d_out
+        elif kind == "sage":
+            total += n * d_in + 2 * n * (2 * d_in) * d_out
+        else:
+            raise ValueError(kind)
+    return total
+
+
+def aggregation_per_forward(layer_dims, n: int, arcs: int) -> tuple[int, int]:
+    """(flops, bytes) of every layer's neighbour sum in one forward."""
+    flops = nbytes = 0
+    for d in layer_dims[:-1]:
+        f, b = aggregation(n, arcs, d)
+        flops, nbytes = flops + f, nbytes + b
+    return flops, nbytes
