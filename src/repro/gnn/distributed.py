@@ -48,6 +48,7 @@ Plan lifecycle (compile -> patch -> retrace):
 from __future__ import annotations
 
 import dataclasses
+import time
 from typing import Optional, Sequence
 
 import jax
@@ -1258,6 +1259,13 @@ def make_bsp_forward(
     ``exchange='ppermute'`` moves only cut-link rows (GLAD-aware);
     ``'allgather'`` is the layout-agnostic baseline.  ``aggregate`` picks
     the per-device neighbor sum — see :func:`resolve_aggregate`.
+
+    The jitted program is named ``jit_bsp_forward`` in compiled text and in
+    profiler traces.  Tracing: set ``fwd.spans = []`` and every call appends
+    ``("bsp.dispatch", start_ns, end_ns)`` on ``time.perf_counter_ns``, from
+    entry until the program is dispatched (signature and operand checks
+    included; the device work is not waited for).  Set it back to ``None``
+    (the default) to stop recording.
     """
     mode = resolve_aggregate(cfg, aggregate)
     if mode == "pallas" and plan.bsr is None:
@@ -1309,7 +1317,7 @@ def make_bsp_forward(
         n_rounds = len(shifts) if exchange == "ppermute" else 0
         has_repl = _use_replicas()
 
-        def inner(params, blocks, *rest):
+        def bsp_forward(params, blocks, *rest):
             state["traces"] += 1         # python body runs once per trace
             if has_repl:
                 halo0_blk, ops = rest[0], rest[1:]
@@ -1346,7 +1354,7 @@ def make_bsp_forward(
         n_ops = n_fixed + 2 * n_rounds * (2 if has_repl else 1)
         n_lead = 1 if has_repl else 0
         smapped = jax.shard_map(
-            inner, mesh=mesh,
+            bsp_forward, mesh=mesh,
             in_specs=(P(), spec_b) + (spec_b,) * (n_lead + n_ops),
             out_specs=spec_b)
         return jax.jit(smapped)
@@ -1371,13 +1379,17 @@ def make_bsp_forward(
         return (replica0,)
 
     def forward(params, blocks, replica0=None):
+        t0 = time.perf_counter_ns()
         fn = _fn()
         if state["version"] != plan.version:
             # One block per device, placed once per plan version.
             state["ops"] = tuple(jax.device_put(a, sharded)
                                  for a in _operands())
             state["version"] = plan.version
-        return fn(params, blocks, *_lead(replica0), *state["ops"])
+        out = fn(params, blocks, *_lead(replica0), *state["ops"])
+        if forward.spans is not None:
+            forward.spans.append(("bsp.dispatch", t0, time.perf_counter_ns()))
+        return out
 
     def lower(params, blocks, replica0=None):
         ops = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharded)
@@ -1385,6 +1397,7 @@ def make_bsp_forward(
         return _fn().lower(params, blocks, *_lead(replica0), *ops)
 
     forward.stats = state
+    forward.spans = None
     forward.lower = lower
     forward.plan = plan
     return forward

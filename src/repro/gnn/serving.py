@@ -273,7 +273,9 @@ def make_ego_forward(cfg: GNNConfig, params, jit: bool = True):
     flattened union graph, so semantics (and, with full fanout, bits) match
     the whole-graph forward at the target rows.  ``fwd.stats['traces']``
     counts jit traces (incremented at trace time — the make_bsp_forward
-    contract): bucketed shapes bound it by O(log) per dimension.
+    contract): bucketed shapes bound it by O(log) per dimension.  The
+    jitted program is named ``jit__fwd`` in compiled text and in profiler
+    traces; ``fwd.lower(*args)`` lowers it without running it.
 
     ``jit=False`` runs the same program eagerly.  Agreement with the
     whole-graph reference (:func:`repro.gnn.models.reference_forward`,
@@ -307,6 +309,8 @@ def make_ego_forward(cfg: GNNConfig, params, jit: bool = True):
         return jfn(feats, arcs, deg, tgt_rows)
 
     fwd.stats = state
+    if jit:
+        fwd.lower = jfn.lower
     return fwd
 
 
@@ -399,6 +403,8 @@ class ServeStats:
     fetched_rows: int = 0        # remote rows pulled cross-server
     fetch_cost: float = 0.0      # sum tau[home, owner] over fetched rows
     plan_refreshes: int = 0      # cache re-seeds after plan.version moved
+    rows: int = 0                # real ego rows the forward computed
+    row_slots: int = 0           # rows it computed, padding and dummy too
 
     @property
     def throughput_rps(self) -> float:
@@ -425,7 +431,19 @@ class GNNServeEngine:
     plan's rows — the ledger before this snapshot silently mixed plans),
     and ``epoch_history`` keeps the closed epochs.  ``hops`` defaults to
     the model depth (exact receptive field); ``fanout`` bounds per-hop
-    neighbors (None = exact)."""
+    neighbors (None = exact).
+
+    Tracing: set ``engine.spans = []`` and every :meth:`tick` appends
+    ``(name, start_ns, end_ns)`` tuples on ``time.perf_counter_ns``:
+    ``serve.tick`` for the whole tick and, inside it, one after the other,
+    ``serve.extract`` (popping the batch and :func:`extract_ego_batch`),
+    ``serve.account`` (row accounting against the plan), ``serve.tables``
+    (:func:`ego_tables`), ``serve.h2d`` (the copies to the device),
+    ``serve.dispatch`` (the forward's call until it returns) and
+    ``serve.fetch`` (until the output rows are on the host).  Set it back
+    to ``None`` (the default) to stop recording.  ``stats.rows`` over
+    ``stats.row_slots`` is the share of the forward's rows that are real
+    ego rows rather than bucket padding."""
 
     def __init__(self, cfg: GNNConfig, params, graph: DataGraph,
                  plan: ShardPlan, features: Optional[np.ndarray] = None,
@@ -451,6 +469,7 @@ class GNNServeEngine:
         self.epoch_stats = ServeStats()
         self.epoch_latencies: List[float] = []
         self.epoch_history: List[dict] = []
+        self.spans: Optional[list] = None   # (name, start_ns, end_ns) per phase
         self.fwd = make_ego_forward(cfg, params)
         self._degrees = graph.degrees.astype(np.float32)
         self._caches: Dict[int, FeatureCache] = {}
@@ -551,21 +570,38 @@ class GNNServeEngine:
         if self._plan_version != self.plan.version:
             self._refresh_caches()
             self.stats.plan_refreshes += 1
-        t0 = time.perf_counter()
+        t0 = time.perf_counter_ns()
         take = min(self.batch, len(self.queue))
         items = [self.queue.popleft() for _ in range(take)]
         targets = np.array([t for t, _ in items], dtype=np.int64)
         ego = extract_ego_batch(self.graph, targets, self.hops, self.fanout,
                                 batch=self.batch)
+        t1 = time.perf_counter_ns()
         self._account(ego, targets)
+        t2 = time.perf_counter_ns()
         feats, deg, tgt_rows = ego_tables(ego, self.features, self._degrees)
-        out = np.asarray(self.fwd(jnp.asarray(feats), jnp.asarray(ego.arcs),
-                                  jnp.asarray(deg), jnp.asarray(tgt_rows)))
-        now = time.perf_counter()
+        t3 = time.perf_counter_ns()
+        args = (jnp.asarray(feats), jnp.asarray(ego.arcs), jnp.asarray(deg),
+                jnp.asarray(tgt_rows))
+        t4 = time.perf_counter_ns()
+        out = self.fwd(*args)
+        t5 = time.perf_counter_ns()
+        out = np.asarray(out)
+        t6 = time.perf_counter_ns()
+        if self.spans is not None:
+            self.spans.extend((
+                ("serve.tick", t0, t6), ("serve.extract", t0, t1),
+                ("serve.account", t1, t2), ("serve.tables", t2, t3),
+                ("serve.h2d", t3, t4), ("serve.dispatch", t4, t5),
+                ("serve.fetch", t5, t6)))
+        rows, slots = int(ego.num_nodes.sum()), ego.dummy + 1
         for st in (self.stats, self.epoch_stats):
-            st.wall_time_s += now - t0
+            st.wall_time_s += (t6 - t0) * 1e-9
             st.batches += 1
             st.requests += take
+            st.rows += rows
+            st.row_slots += slots
+        now = t6 * 1e-9                  # perf_counter_ns's clock, as submit's
         for _, ts in items:
             self.latencies.append(now - ts)
             self.epoch_latencies.append(now - ts)

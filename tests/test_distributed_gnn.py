@@ -69,6 +69,27 @@ def test_simulate_matches_full_forward(model, small_siot):
     np.testing.assert_allclose(out, ref, rtol=2e-4, atol=2e-4)
 
 
+def test_bsp_forward_records_its_dispatch_and_is_named(small_siot):
+    from repro.gnn.distributed import make_bsp_forward, scatter_features
+    from repro.launch.mesh import make_mesh
+
+    g = small_siot
+    _, _, plan = _plan_for(g, 1)
+    cfg = GNNConfig("gcn", (g.features.shape[1], 16, 2))
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    mesh = make_mesh((1,), ("data",))
+    fwd = make_bsp_forward(cfg, plan, mesh, aggregate="segment")
+    blocks = jnp.asarray(scatter_features(plan, g.features))
+    assert fwd.spans is None
+    plain = np.asarray(fwd(params, blocks))
+    fwd.spans = []
+    traced = np.asarray(fwd(params, blocks))
+    assert [n for n, _, _ in fwd.spans] == ["bsp.dispatch"]
+    assert fwd.spans[0][1] <= fwd.spans[0][2]
+    np.testing.assert_array_equal(plain, traced)
+    assert "module @jit_bsp_forward " in fwd.lower(params, blocks).as_text()
+
+
 _SUBPROCESS = textwrap.dedent("""
     import os
     os.environ['XLA_FLAGS'] = '--xla_force_host_platform_device_count=4'

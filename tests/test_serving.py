@@ -341,6 +341,68 @@ def test_engine_fetch_accounting_against_plan(served_cluster):
         assert s.cache_hit_rows + s.fetched_rows > 0
 
 
+# ------------------------------------------------------------ spans, counters
+PHASES = ("serve.extract", "serve.account", "serve.tables", "serve.h2d",
+          "serve.dispatch", "serve.fetch")
+
+
+def test_traced_tick_records_each_phase_once_inside_the_tick(served_cluster):
+    g, cfg, params, plan = served_cluster
+    eng = GNNServeEngine(cfg, params, g, plan, batch=4)
+    eng.submit([0, 40, 90])
+    eng.spans = []
+    eng.tick()
+    assert sorted(n for n, _, _ in eng.spans) == sorted(PHASES
+                                                        + ("serve.tick",))
+    (tick,) = [sp for sp in eng.spans if sp[0] == "serve.tick"]
+    phases = [sp for sp in eng.spans if sp[0] != "serve.tick"]
+    assert tuple(n for n, _, _ in phases) == PHASES
+    assert all(s <= e for _, s, e in eng.spans)
+    assert tick[1] <= phases[0][1] and phases[-1][2] <= tick[2]
+    for a, b in zip(phases, phases[1:]):
+        assert a[2] <= b[1]                  # disjoint and in order
+
+
+def test_untraced_tick_records_nothing_and_serves_the_same_bits(
+        served_cluster):
+    g, cfg, params, plan = served_cluster
+    eng = GNNServeEngine(cfg, params, g, plan, batch=4)
+    targets = np.array([0, 40, 90, 120])
+    assert eng.spans is None
+    eng.spans = spans = []
+    eng.submit(targets)
+    traced = eng.tick()
+    eng.spans = None
+    eng.submit(targets)
+    plain = eng.tick()
+    assert len(spans) == 1 + len(PHASES)
+    np.testing.assert_array_equal(plain, traced)
+
+
+def test_row_counters_are_the_batch_counts(served_cluster):
+    g, cfg, params, plan = served_cluster
+    eng = GNNServeEngine(cfg, params, g, plan, batch=4)
+    targets = np.array([0, 40, 90])
+    ego = extract_ego_batch(g, targets, eng.hops, eng.fanout, batch=4)
+    eng.submit(targets)
+    eng.tick()
+    assert eng.stats.rows == int(ego.num_nodes.sum())
+    assert eng.stats.row_slots == ego.dummy + 1
+    assert (eng.epoch_stats.rows, eng.epoch_stats.row_slots) == \
+        (eng.stats.rows, eng.stats.row_slots)
+
+
+def test_ego_forward_program_is_named_jit__fwd(served_cluster):
+    g, cfg, params, _ = served_cluster
+    ego = extract_ego_batch(g, np.array([0, 40]), cfg.num_layers, batch=4)
+    feats, deg, tgt = ego_tables(ego, g.features,
+                                 g.degrees.astype(np.float32))
+    fwd = make_ego_forward(cfg, params)
+    text = fwd.lower(jnp.asarray(feats), jnp.asarray(ego.arcs),
+                     jnp.asarray(deg), jnp.asarray(tgt)).as_text()
+    assert "module @jit__fwd " in text
+
+
 # ---------------------------------------------------------------- serving cost
 def test_serving_cost_guards_and_orders_layouts(cm_small):
     cm = cm_small
