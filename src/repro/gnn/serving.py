@@ -10,6 +10,12 @@ Fograph scenario).  This module is that request path:
     shapes: fixed fanout per hop, node/arc counts padded to power-of-2
     buckets (the graphbolt ``neighbor_sampler`` idiom), so the jitted
     forward traces O(log) specializations instead of one per request.
+    Both are one BFS over every target of a batch at once (one
+    ``csr_multirange`` per hop over the whole (request, vertex)
+    frontier), costing the egos' total size and no Python loop per
+    request; each request keeps the per-target order — target first,
+    then each depth's new vertices in ascending id; arcs by hop, then
+    destination in ascending id, then CSR order.
   * :func:`make_ego_forward` — the batched ego inference, reusing the
     EXACT layer functions of :mod:`repro.gnn.models`.  With full fanout
     the target rows reproduce the whole-graph forward within f32
@@ -134,54 +140,92 @@ def link_traffic(graph: DataGraph, targets: np.ndarray, hops: int,
 
 
 # ------------------------------------------------------------- ego extraction
+def _ego_walk(graph: DataGraph, targets: np.ndarray, hops: int,
+              fanout: Optional[int] = None):
+    """One BFS over the ``hops``-egos of every target at once.
+
+    The frontier is a set of (request, vertex) pairs, each depth's kept as
+    its sorted ``request * n + vertex`` keys, so a vertex reached by two
+    requests is new in both and the state is the size of the egos.  Each
+    hop gathers the whole frontier's CSR rows in one
+    :func:`csr_multirange`, caps each row at its first ``fanout`` entries,
+    and finds the new pairs by one ``np.unique`` of the hop's keys and a
+    ``searchsorted`` into each earlier depth.
+
+    Returns flat arrays in WALK order, depth by depth and, within a depth,
+    by (request, vertex): the nodes as ``(req, vertex, depth)`` and the
+    arcs as ``(req, src, dst)``, ``src``/``dst`` being walk indices into
+    the nodes.  A hop's arcs run by request, then frontier vertex in
+    ascending id, then CSR order.  With one target, walk order is the
+    ego's own order."""
+    indptr, indices, n = graph.indptr, graph.indices, graph.n
+    fv = np.asarray(targets, dtype=np.int64)
+    fr = np.arange(len(fv), dtype=np.int64)
+    levels = [fr * n + fv]                 # sorted keys of each depth
+    areqs, srcs, dsts = [], [], []
+    first, total = 0, len(fv)              # walk index of frontier, of end
+    for _ in range(hops):
+        if not len(fv):
+            break
+        flat, rep = csr_multirange(indptr, fv)
+        nbrs = indices[flat].astype(np.int64)
+        if fanout is not None and len(nbrs):
+            counts = indptr[fv + 1] - indptr[fv]
+            within = (np.arange(len(flat))
+                      - np.repeat(np.cumsum(counts) - counts, counts))
+            keep = within < fanout
+            nbrs, rep = nbrs[keep], rep[keep]
+        r = fr[rep]
+        keys, inv = np.unique(r * n + nbrs, return_inverse=True)
+        at = np.full(len(keys), -1, dtype=np.int64)    # walk index per key
+        base = 0
+        for lvl in levels:
+            if len(lvl):
+                pos = np.searchsorted(lvl, keys)
+                pos[pos == len(lvl)] = 0
+                hit = lvl[pos] == keys
+                at[hit] = base + pos[hit]
+            base += len(lvl)
+        fresh = at < 0
+        new = keys[fresh]
+        at[fresh] = total + np.arange(len(new))
+        areqs.append(r)
+        srcs.append(at[inv])
+        dsts.append(first + rep)
+        first, total = total, total + len(new)
+        levels.append(new)
+        fr, fv = new // n, new % n
+
+    keys = np.concatenate(levels)
+    depth = np.repeat(np.arange(len(levels)), [len(k) for k in levels])
+
+    def cat(parts):
+        return np.concatenate(parts) if parts else np.zeros(0, np.int64)
+
+    return (keys // n, keys % n, depth), (cat(areqs), cat(srcs), cat(dsts))
+
+
 def extract_ego(graph: DataGraph, target: int, hops: int,
                 fanout: Optional[int] = None):
     """k-hop ego subgraph of ``target``: (nodes, arcs, depth).
 
     ``nodes`` (global ids, ``nodes[0] == target``) are the vertices within
-    ``hops``; ``arcs`` (global (src, dst)) are ALL incoming arcs of every
-    node at depth < hops — exactly what a ``hops``-layer GNN needs to
-    reproduce the whole-graph output at the target (depth-``hops`` nodes
-    contribute raw features only, so they carry no arcs).  Per-destination
-    arcs are contiguous in ascending src order — the same summation order
-    as the full-graph ``directed_edges`` path, which keeps the ego forward
-    within f32 reduction-order tolerance of the whole-graph forward.
-    ``fanout`` truncates each node's neighbor list to its first ``fanout``
-    entries (ascending-id prefix — deterministic sampling; ``None`` / >=
-    max degree is exact)."""
-    indptr, indices = graph.indptr, graph.indices
-    visited = np.zeros(graph.n, dtype=bool)
-    visited[target] = True
-    nodes = [np.array([target], dtype=np.int64)]
-    depths = [np.zeros(1, dtype=np.int64)]
-    srcs, dsts = [], []
-    frontier = np.array([target], dtype=np.int64)
-    for d in range(hops):
-        if not len(frontier):
-            break
-        flat, rep = csr_multirange(indptr, frontier)
-        nbrs = indices[flat]
-        if fanout is not None and len(nbrs):
-            counts = indptr[frontier + 1] - indptr[frontier]
-            within = (np.arange(len(flat))
-                      - np.repeat(np.cumsum(counts) - counts, counts))
-            keep = within < fanout
-            nbrs, rep = nbrs[keep], rep[keep]
-        srcs.append(nbrs.astype(np.int64))
-        dsts.append(frontier[rep])
-        new = np.unique(nbrs[~visited[nbrs]])
-        if len(new):
-            visited[new] = True
-            nodes.append(new.astype(np.int64))
-            depths.append(np.full(len(new), d + 1, dtype=np.int64))
-        frontier = new.astype(np.int64)
-    all_nodes = np.concatenate(nodes)
-    all_depth = np.concatenate(depths)
-    if srcs:
-        arcs = np.stack([np.concatenate(srcs), np.concatenate(dsts)], axis=1)
-    else:
-        arcs = np.zeros((0, 2), dtype=np.int64)
-    return all_nodes, arcs, all_depth
+    ``hops``: the target, then each depth's new vertices in ascending id;
+    ``depth`` is each node's hop count.  ``arcs`` (global (src, dst)) are
+    ALL incoming arcs of every node at depth < hops — exactly what a
+    ``hops``-layer GNN needs to reproduce the whole-graph output at the
+    target (depth-``hops`` nodes contribute raw features only, so they
+    carry no arcs) — grouped by hop, then by destination in ascending id,
+    each destination's arcs in ascending src (CSR) order: the same
+    summation order as the full-graph ``directed_edges`` path, which keeps
+    the ego forward within f32 reduction-order tolerance of the
+    whole-graph forward.  ``fanout`` truncates each node's neighbor list to
+    its first ``fanout`` entries (ascending-id prefix — deterministic
+    sampling; ``None`` / >= max degree is exact).  This is the batched walk
+    of :func:`extract_ego_batch` with one target."""
+    (_, nodes, depth), (_, src, dst) = _ego_walk(graph, [target], hops,
+                                                  fanout)
+    return nodes, np.stack([nodes[src], nodes[dst]], axis=1), depth
 
 
 @dataclasses.dataclass
@@ -219,32 +263,42 @@ def extract_ego_batch(graph: DataGraph, targets: np.ndarray, hops: int,
     """Batched extraction with jit-stable shapes: ``node_cap`` (per-request
     node slots) and the arc count are padded to power-of-2 buckets, and the
     batch dimension to ``batch`` (short final batches pad with empty
-    requests, target -1)."""
+    requests, target -1).
+
+    One walk (:func:`_ego_walk`) extracts every request's ego at once; its
+    cost grows with the egos' total size, not with the number of requests.
+    Each request gets exactly :func:`extract_ego`'s nodes and arcs, in its
+    order (a repeated target gets its own identical ego; an isolated one a
+    single node and no arcs): a stable sort by request turns the walk's
+    depth-major order into each request's, and since the walk names each
+    arc's ends by node index, arcs reach their local slots by a gather."""
     targets = np.asarray(targets, dtype=np.int64)
     B = int(batch) if batch is not None else len(targets)
     if len(targets) > B:
         raise ValueError(f"{len(targets)} targets > batch {B}")
-    egos = [extract_ego(graph, int(t), hops, fanout) for t in targets]
-    node_cap = _pow2(max((len(nd) for nd, _, _ in egos), default=1))
-    arc_cap = _pow2(max(sum(len(a) for _, a, _ in egos), 1))
+    (req, verts, _), (areq, src, dst) = _ego_walk(graph, targets, hops,
+                                                  fanout)
+    num_nodes = np.bincount(req, minlength=B)
+    node_cap = _pow2(num_nodes.max(initial=1))
+    arc_cap = _pow2(max(len(src), 1))
+    # Walk index -> local flat id b * node_cap + slot, slot = position in
+    # the request's own order.
+    order = np.argsort(req, kind="stable")
+    first = np.cumsum(num_nodes) - num_nodes
+    rs = req[order]
+    local = np.empty(len(req), dtype=np.int64)
+    local[order] = rs * node_cap + np.arange(len(req)) - first[rs]
     nodes = np.full((B, node_cap), -1, dtype=np.int64)
-    num_nodes = np.zeros(B, dtype=np.int64)
+    nodes.reshape(-1)[local] = verts
     dummy = B * node_cap
     arcs = np.full((arc_cap, 2), dummy, dtype=np.int32)
+    order = np.argsort(areq, kind="stable")
+    arcs[: len(order), 0] = local[src[order]]
+    arcs[: len(order), 1] = local[dst[order]]
     tgt = np.full(B, -1, dtype=np.int64)
-    at = 0
-    for b, (nd, ac, _) in enumerate(egos):
-        nodes[b, : len(nd)] = nd
-        num_nodes[b] = len(nd)
-        tgt[b] = targets[b]
-        if len(ac):
-            # global -> local slot within this request (nd rows are unique).
-            order = np.argsort(nd, kind="stable")
-            pos = order[np.searchsorted(nd[order], ac)]
-            arcs[at: at + len(ac)] = (b * node_cap + pos).astype(np.int32)
-            at += len(ac)
+    tgt[: len(targets)] = targets
     return EgoBatch(nodes=nodes, arcs=arcs, targets=tgt,
-                    num_nodes=num_nodes, num_arcs=at, hops=hops,
+                    num_nodes=num_nodes, num_arcs=len(order), hops=hops,
                     fanout=fanout)
 
 

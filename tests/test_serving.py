@@ -10,10 +10,14 @@ from repro.core.partition import partition_from_assign
 from repro.gnn.distributed import compile_plan, patch_plan
 from repro.gnn.models import (GNNConfig, directed_edges, forward,
                               init_params, reference_forward)
-from repro.gnn.serving import (FeatureCache, GNNServeEngine, ego_tables,
-                               extract_ego, extract_ego_batch, link_traffic,
+from repro.gnn import serving
+from repro.gnn.serving import (EgoBatch, FeatureCache, GNNServeEngine,
+                               _pow2, ego_tables, extract_ego,
+                               extract_ego_batch, link_traffic,
                                make_ego_forward, request_traffic,
                                serving_cost, zipf_requests)
+from repro.graphs.datagraph import DataGraph, csr_multirange
+from repro.graphs.edgenet import build_edge_network
 from tests.conftest import random_graph
 
 # The ego forward sums in f32 in another order than the whole-graph
@@ -101,6 +105,131 @@ def test_extract_ego_batch_padding_invariants(small_siot):
         assert ego.num_nodes[b] >= 1
     real = ego.arcs[: ego.num_arcs]
     assert (real < ego.dummy).all() and (real >= 0).all()
+
+
+# The per-target extraction the batched walk replaced, kept verbatim as the
+# oracle: one BFS per target, then a per-request argsort/searchsorted.
+def _oracle_extract_ego(graph, target, hops, fanout=None):
+    indptr, indices = graph.indptr, graph.indices
+    visited = np.zeros(graph.n, dtype=bool)
+    visited[target] = True
+    nodes = [np.array([target], dtype=np.int64)]
+    depths = [np.zeros(1, dtype=np.int64)]
+    srcs, dsts = [], []
+    frontier = np.array([target], dtype=np.int64)
+    for d in range(hops):
+        if not len(frontier):
+            break
+        flat, rep = csr_multirange(indptr, frontier)
+        nbrs = indices[flat]
+        if fanout is not None and len(nbrs):
+            counts = indptr[frontier + 1] - indptr[frontier]
+            within = (np.arange(len(flat))
+                      - np.repeat(np.cumsum(counts) - counts, counts))
+            keep = within < fanout
+            nbrs, rep = nbrs[keep], rep[keep]
+        srcs.append(nbrs.astype(np.int64))
+        dsts.append(frontier[rep])
+        new = np.unique(nbrs[~visited[nbrs]])
+        if len(new):
+            visited[new] = True
+            nodes.append(new.astype(np.int64))
+            depths.append(np.full(len(new), d + 1, dtype=np.int64))
+        frontier = new.astype(np.int64)
+    all_nodes = np.concatenate(nodes)
+    all_depth = np.concatenate(depths)
+    if srcs:
+        arcs = np.stack([np.concatenate(srcs), np.concatenate(dsts)], axis=1)
+    else:
+        arcs = np.zeros((0, 2), dtype=np.int64)
+    return all_nodes, arcs, all_depth
+
+
+def _oracle_extract_ego_batch(graph, targets, hops, fanout=None, batch=None):
+    targets = np.asarray(targets, dtype=np.int64)
+    B = int(batch) if batch is not None else len(targets)
+    if len(targets) > B:
+        raise ValueError(f"{len(targets)} targets > batch {B}")
+    egos = [_oracle_extract_ego(graph, int(t), hops, fanout)
+            for t in targets]
+    node_cap = _pow2(max((len(nd) for nd, _, _ in egos), default=1))
+    arc_cap = _pow2(max(sum(len(a) for _, a, _ in egos), 1))
+    nodes = np.full((B, node_cap), -1, dtype=np.int64)
+    num_nodes = np.zeros(B, dtype=np.int64)
+    dummy = B * node_cap
+    arcs = np.full((arc_cap, 2), dummy, dtype=np.int32)
+    tgt = np.full(B, -1, dtype=np.int64)
+    at = 0
+    for b, (nd, ac, _) in enumerate(egos):
+        nodes[b, : len(nd)] = nd
+        num_nodes[b] = len(nd)
+        tgt[b] = targets[b]
+        if len(ac):
+            # global -> local slot within this request (nd rows are unique).
+            order = np.argsort(nd, kind="stable")
+            pos = order[np.searchsorted(nd[order], ac)]
+            arcs[at: at + len(ac)] = (b * node_cap + pos).astype(np.int32)
+            at += len(ac)
+    return EgoBatch(nodes=nodes, arcs=arcs, targets=tgt,
+                    num_nodes=num_nodes, num_arcs=at, hops=hops,
+                    fanout=fanout)
+
+
+def _graph_with_isolated_vertices():
+    """40 vertices, links only among the first 25: 15 isolated."""
+    rng = np.random.default_rng(5)
+    e = rng.integers(0, 25, size=(60, 2))
+    return DataGraph(n=40, edges=e[e[:, 0] != e[:, 1]])
+
+
+def _assert_same_batch(got, want):
+    for field in ("nodes", "arcs", "targets", "num_nodes"):
+        x, y = getattr(got, field), getattr(want, field)
+        assert x.dtype == y.dtype, field
+        np.testing.assert_array_equal(x, y, err_msg=field)
+    assert got.num_arcs == want.num_arcs
+    assert got.node_cap == want.node_cap
+    assert got.arcs.shape[0] == want.arcs.shape[0]          # arc bucket
+    assert (got.batch, got.hops, got.fanout) == \
+        (want.batch, want.hops, want.fanout)
+
+
+@pytest.mark.parametrize("fanout", [None, 1, 3])
+@pytest.mark.parametrize("hops", [1, 2, 3])
+@pytest.mark.parametrize("graph", ["random", "small_siot", "isolated"])
+def test_batched_walk_matches_per_target_oracle(graph, hops, fanout,
+                                                request):
+    if graph == "random":
+        rng = np.random.default_rng(hops * 7 + (fanout or 0))
+        g = random_graph(rng, int(rng.integers(20, 60)), 40)
+    elif graph == "isolated":
+        g = _graph_with_isolated_vertices()
+    else:
+        g = request.getfixturevalue(graph)
+    rng = np.random.default_rng(17)
+    deg = g.degrees
+    hub = int(np.argmax(deg))
+    lonely = np.flatnonzero(deg == 0)
+    batches = [
+        (np.array([hub, 3, hub, hub, 3]), 8),      # repeated targets
+        (rng.integers(0, g.n, size=8), 8),         # len == batch
+        (rng.integers(0, g.n, size=3), 8),         # short batch
+        (np.array([hub]), 1),
+        (np.zeros(0, dtype=np.int64), 4),          # empty batch
+    ]
+    if len(lonely):                                # isolated targets
+        batches.append((np.concatenate([lonely[:3], [hub], lonely[:1]]), 8))
+        batches.append((lonely[:4], 4))
+    for targets, batch in batches:
+        _assert_same_batch(
+            extract_ego_batch(g, targets, hops, fanout, batch=batch),
+            _oracle_extract_ego_batch(g, targets, hops, fanout, batch=batch))
+    for t in range(g.n):
+        got = extract_ego(g, t, hops, fanout)
+        want = _oracle_extract_ego(g, t, hops, fanout)
+        for x, y in zip(got, want):
+            assert x.dtype == y.dtype and x.shape == y.shape
+            np.testing.assert_array_equal(x, y)
 
 
 # ----------------------------------------------------------------- ego forward
@@ -339,6 +468,32 @@ def test_engine_fetch_accounting_against_plan(served_cluster):
     # unless every ego row happened to be local.
     if s.local_rows < total:
         assert s.cache_hit_rows + s.fetched_rows > 0
+
+
+@pytest.mark.parametrize("cache_bytes", [1 << 8, 1 << 22])
+def test_engine_batched_walk_serves_as_the_oracle_extraction(
+        served_cluster, cache_bytes, monkeypatch):
+    """The forward and the row accounting see the same egos: a Zipf stream
+    served with the batched walk and with the per-target oracle patched in
+    gives the same bits and the same ServeStats."""
+    g, cfg, params, plan = served_cluster
+    net = build_edge_network(g, 4, seed=0)
+    targets = zipf_requests(g.n, 61, seed=4)
+
+    def serve():
+        eng = GNNServeEngine(cfg, params, g, plan, batch=8,
+                             cache_bytes=cache_bytes, net=net)
+        return eng.serve(targets), eng.stats
+
+    out, stats = serve()
+    monkeypatch.setattr(serving, "extract_ego_batch",
+                        _oracle_extract_ego_batch)
+    want_out, want = serve()
+    np.testing.assert_array_equal(out, want_out)
+    for k in ("local_rows", "cache_hit_rows", "fetched_rows", "fetch_cost",
+              "rows", "row_slots", "requests", "batches"):
+        assert getattr(stats, k) == getattr(want, k), k
+    assert stats.fetched_rows > 0 and stats.cache_hit_rows > 0
 
 
 # ------------------------------------------------------------ spans, counters
