@@ -23,7 +23,7 @@ BENCH = Path(__file__).resolve().parent
 sys.path.insert(0, str(BENCH))
 sys.path.insert(1, str(BENCH.parent / "src"))
 
-from harness import check, graphs, inputs, reference, registry, traffic  # noqa: E402
+from harness import check, graphs, inputs, reference, registry  # noqa: E402
 
 
 def control_readings(cell: dict, seed: int, seconds: float,
@@ -31,15 +31,13 @@ def control_readings(cell: dict, seed: int, seconds: float,
     """The cell's compared numbers, read off the control."""
     cfg, prm = cell["config"], cell["params"]
     n, edges, _ = graphs.build(cfg["graph"])
-    feats, weights = inputs.make(cfg["model"], n, seed, device)
-    kind = cfg["model"]["kind"]
-    ref_mode, low_mode = reference.modes(cfg["model"])
-    ref = reference.forward(kind, weights, feats, edges, ref_mode)
-    low = reference.forward(kind, weights, feats, edges, low_mode)
-    if prm["driver"] == "ego":
-        _, rows = traffic.schedule(prm, n, seconds, seed)
-    else:
-        rows = slice(None)
+    model = cfg["model"]
+    feats, weights = inputs.make(model, n, seed, device)
+    ref_mode, low_mode = reference.modes(model)
+    ref = reference.forward(model, weights, feats, edges, ref_mode)
+    low = reference.forward(model, weights, feats, edges, low_mode)
+    rows = registry.driver(prm["driver"]).compared_rows(prm, n, seconds,
+                                                        seed)
     got = check.readings(low[rows], ref[rows], check.scale_of(ref))
     return {k: got[k] for k in cell["limits"]}
 

@@ -49,12 +49,17 @@ def bucket_grid(nodes: np.ndarray, arcs: np.ndarray, batch: int) -> list:
     return out
 
 
+def compared_rows(params: dict, n: int, seconds: float, seed: int):
+    """The vertices whose rows a run compares: the window's targets."""
+    return traffic.schedule(params, n, seconds, seed)[1]
+
+
 class Driver:
     def __init__(self, cell: dict, seed: int, seconds: float, devices):
         from repro.gnn import GNNServeEngine
 
         cfg, prm = cell["config"], cell["params"]
-        self.prm, self.kind = prm, cfg["model"]["kind"]
+        self.prm, self.model = prm, cfg["model"]
         self.ref_mode = reference.modes(cfg["model"])[0]
         self.dep = fleet.build(cfg, seed, devices[0])
         net, plan = fleet.layout(cfg, self.dep, int(cfg["fleet"]["servers"]))
@@ -150,7 +155,7 @@ class Driver:
     # -------------------------------------------------------- correctness
     def readings(self) -> dict:
         """Every answered request against the reference's row."""
-        ref = reference.forward(self.kind, self.dep.weights,
+        ref = reference.forward(self.model, self.dep.weights,
                                 self.dep.feats_dev, self.dep.edges,
                                 self.ref_mode)
         ok = np.isfinite(self.done)

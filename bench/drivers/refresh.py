@@ -21,13 +21,18 @@ KEPT = 4          # refreshes kept for the comparison, besides the last
 KEPT_AMONG = 64   # drawn among the window's first refreshes
 
 
+def compared_rows(params: dict, n: int, seconds: float, seed: int):
+    """The vertices whose rows a run compares: every one."""
+    return slice(None)
+
+
 class Driver:
     def __init__(self, cell: dict, seed: int, seconds: float, devices):
         from repro.gnn import make_bsp_forward, scatter_features
         from repro.launch.mesh import make_mesh
 
         cfg, prm = cell["config"], cell["params"]
-        self.kind = cfg["model"]["kind"]
+        self.model = cfg["model"]
         self.ref_mode = reference.modes(cfg["model"])[0]
         parts = int(prm["partitions"])
         self.dep = fleet.build(cfg, seed, devices[0])
@@ -75,7 +80,7 @@ class Driver:
         outs = [gather_outputs(self.plan, np.asarray(o), self.dep.n)
                 for o in self.kept]
         self.kept = None
-        ref = reference.forward(self.kind, self.dep.weights,
+        ref = reference.forward(self.model, self.dep.weights,
                                 self.dep.feats_dev, self.dep.edges,
                                 self.ref_mode)
         scale = check.scale_of(ref)

@@ -7,7 +7,7 @@ import dataclasses
 
 import numpy as np
 
-from harness import graphs, inputs
+from harness import graphs, inputs, registry
 
 
 @dataclasses.dataclass
@@ -16,7 +16,7 @@ class Deployment:
     edges: np.ndarray          # (E, 2) canonical links
     feats_dev: object          # (n, d_0) on the first device
     feats: np.ndarray          # the same on the host
-    weights: list              # [{"w": ...}] on the first device
+    weights: list              # per layer, the kind's leaves, on the first device
     graph: object              # the program's DataGraph
     model: object              # the program's GNNConfig
 
@@ -30,9 +30,11 @@ def build(config: dict, seed: int, device) -> Deployment:
     feats = np.asarray(feats_dev)
     g = DataGraph(n=n, edges=edges, features=feats, coords=coords)
     m = config["model"]
+    fields = registry.kind(m["kind"]).config_fields(m)
     return Deployment(n=n, edges=edges, feats_dev=feats_dev, feats=feats,
                       weights=weights, graph=g,
-                      model=GNNConfig(m["kind"], tuple(m["layer_dims"])))
+                      model=GNNConfig(m["kind"], tuple(m["layer_dims"]),
+                                      **fields))
 
 
 def layout(config: dict, dep: Deployment, parts: int):

@@ -1,18 +1,18 @@
-"""The configurations' data graphs, generated here so that the program
-cannot change what it is measured on.
+"""The configurations' data graphs, generated by the benchmark so that the
+program cannot change what it is measured on.
 
-Both generators follow the paper's description of its two data sets
-(arXiv 2210.17281, Sec. VI-A and Fig. 6): SIoT, 8,001 devices and 33,509
-links with a long-tailed degree law; Yelp, 3,912 reviews and 4,677 links,
-sparse, with many isolated vertices.  They are copies of the program's
-``synthetic_siot`` and ``synthetic_yelp`` edge and coordinate processes
-(features and weights are drawn on the device, see ``inputs``).  The graph
-is the deployment's, so it comes from the configuration's fixed ``seed``,
-not from a run's ``--seed``.
+A configuration's ``graph.generator`` names a file
+``bench/generators/<name>.py`` (``siot``, ``yelp``: the paper's two data
+sets, arXiv 2210.17281, Sec. VI-A and Fig. 6), whose ``generate(n, links,
+seed, area)`` gives the edges and coordinates; features and weights are
+drawn on the device, see ``inputs``.  The graph is the deployment's, so it
+comes from the configuration's fixed ``seed``, not from a run's ``--seed``.
 """
 from __future__ import annotations
 
 import numpy as np
+
+from harness import registry
 
 
 def canonical(edges: np.ndarray, n: int) -> np.ndarray:
@@ -24,7 +24,9 @@ def canonical(edges: np.ndarray, n: int) -> np.ndarray:
     return np.stack([lo[idx], hi[idx]], axis=1)
 
 
-def _trim_to(e: np.ndarray, n: int, links: int, rng) -> np.ndarray:
+def trim_to(e: np.ndarray, n: int, links: int, rng) -> np.ndarray:
+    """Exactly ``links`` canonical links: a draw from ``e``, or ``e`` topped
+    up with random pairs."""
     if len(e) > links:
         e = e[rng.choice(len(e), size=links, replace=False)]
     while len(e) < links:
@@ -33,56 +35,9 @@ def _trim_to(e: np.ndarray, n: int, links: int, rng) -> np.ndarray:
     return canonical(e, n)
 
 
-def siot(n: int, links: int, seed: int, area: float):
-    """Preferential attachment (Barabasi-Albert style), trimmed to the exact
-    link count.  Returns (edges, coords)."""
-    rng = np.random.default_rng(seed)
-    m = max(1, int(round(links / max(n - 1, 1))))
-    src, dst = [], []
-    for a in range(m + 1):
-        for b in range(a + 1, m + 1):
-            src.append(a), dst.append(b)
-    targets = list(range(m + 1)) * 2
-    for v in range(m + 1, n):
-        chosen = {targets[p] for p in rng.choice(len(targets), size=m,
-                                                 replace=False)}
-        for u in chosen:
-            src.append(u), dst.append(v)
-            targets.append(u)
-        targets.extend([v] * len(chosen))
-    e = _trim_to(canonical(np.stack([src, dst], axis=1), n), n, links, rng)
-    coords = rng.uniform(0, area, size=(n, 2)).astype(np.float32)
-    return e, coords
-
-
-def yelp(n: int, links: int, seed: int, area: float):
-    """Reviews by one user form small cliques (Pareto-sized groups), trimmed
-    to the exact link count; clients sit in a downtown mixture with a
-    sparse tail.  Returns (edges, coords)."""
-    rng = np.random.default_rng(seed)
-    edges = []
-    v = 0
-    while v < n:
-        c = int(min(n - v, max(1, rng.pareto(2.5) + 1)))
-        edges += [(a, b) for a in range(v, v + c) for b in range(a + 1, v + c)]
-        v += c
-    e = np.array(edges, dtype=np.int64).reshape(-1, 2)
-    e = _trim_to(canonical(e, n), n, links, rng)
-    centers = rng.uniform(0, area, size=(8, 2))
-    coords = (centers[rng.integers(0, 8, size=n)]
-              + rng.normal(scale=0.6, size=(n, 2)))
-    solitary = rng.uniform(size=n) < 0.1
-    coords[solitary] = rng.uniform(-area * 0.3, area * 1.3,
-                                   size=(int(solitary.sum()), 2))
-    return e, coords.astype(np.float32)
-
-
-GENERATORS = {"siot": siot, "yelp": yelp}
-
-
 def build(graph_cfg: dict):
     """(n, edges, coords) of a configuration's ``graph`` section."""
-    gen = GENERATORS[graph_cfg["generator"]]
+    gen = registry.generator(graph_cfg["generator"])
     n = int(graph_cfg["n"])
     edges, coords = gen(n, int(graph_cfg["links"]), int(graph_cfg["seed"]),
                         float(graph_cfg["area"]))

@@ -8,15 +8,20 @@ program as constants, so weights drawn per run would make every run
 compile each of its ~50 ego-forward programs again.  The features, the
 data the requests read, change with every seed.
 
-The weights take the program's parameter layout (a list of ``{"w": ...}``,
-SAGE's ``w`` being ``(2 * d_in, d_out)``) so they can be handed to it; their
-values come from here, never from the program's ``init_params``.
+The weights take the program's parameter layout, which the model kind's
+file gives (``bench/kinds/<kind>.py``: per layer, each leaf's name, shape
+and fans), so they can be handed to it; their values come from here, never
+from the program's ``init_params``.  Each layer has a key of its own,
+split from ``weights_seed``; its first leaf draws from that key and a
+further leaf ``i`` from the key folded with ``i``.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from harness import registry
 
 
 def key_of(seed: int) -> jax.Array:
@@ -25,25 +30,23 @@ def key_of(seed: int) -> jax.Array:
     return jax.random.PRNGKey(int(word) & 0x7FFFFFFF)
 
 
-def weight_shapes(model: dict) -> list:
-    dims = model["layer_dims"]
-    wide = 2 if model["kind"] == "sage" else 1
-    return [(wide * dims[k], dims[k + 1]) for k in range(len(dims) - 1)]
-
-
 def make(model: dict, n: int, seed: int, device=None):
     """(features (n, d_0) ~ N(0, 1) from ``seed``, weights: Glorot-uniform
-    list from ``model["weights_seed"]``) on ``device``."""
-    shapes = weight_shapes(model)
+    per leaf from ``model["weights_seed"]``) on ``device``."""
+    layout = registry.kind(model["kind"]).weights(model)
     d0 = model["layer_dims"][0]
 
     def draw(key, wkey):
         feats = jax.random.normal(key, (n, d0), jnp.float32)
         weights = []
-        for k, (fi, fo) in zip(jax.random.split(wkey, len(shapes)), shapes):
-            lim = (6.0 / (fi + fo)) ** 0.5
-            weights.append({"w": jax.random.uniform(k, (fi, fo), jnp.float32,
-                                                    -lim, lim)})
+        for k, leaves in zip(jax.random.split(wkey, len(layout)), layout):
+            layer = {}
+            for i, (name, (shape, fi, fo)) in enumerate(leaves.items()):
+                lim = (6.0 / (fi + fo)) ** 0.5
+                lk = jax.random.fold_in(k, i) if i else k
+                layer[name] = jax.random.uniform(lk, shape, jnp.float32,
+                                                 -lim, lim)
+            weights.append(layer)
         return feats, weights
 
     keys = (key_of(seed), key_of(model["weights_seed"]))

@@ -1,23 +1,37 @@
 """Find the benchmark's parts by name: one file per configuration, traffic
-mix, cell and per-layer metric.
+mix, cell, per-layer metric, model kind, driver and graph generator.
 
   bench/configs/<config>.json    graph, model, fleet, source, reduced, assumed
   bench/traffic/<mix>.json       the mix's parameters; ``driver`` names the loop
   bench/workloads/<cell>.json    config, traffic, chips and the cell's own
                                  parameters (rate, limits)
   bench/metrics/<metric>.py      ``read(run) -> float | None``
+  bench/kinds/<kind>.py          a configuration's ``model.kind``: its weight
+                                 layout, reference layer, operation count and
+                                 the program's ``GNNConfig`` fields
+  bench/drivers/<driver>.py      a mix's ``driver``: ``Driver(cell, seed,
+                                 seconds, devices)`` that builds and drives
+                                 the system under test, and
+                                 ``compared_rows(params, n, seconds, seed)``
+  bench/generators/<gen>.py      a configuration's ``graph.generator``:
+                                 ``generate(n, links, seed, area)``
 
-A later PR adds a cell, a mix, a configuration or a metric by adding files;
-nothing here names one.
+A later PR adds a cell, a mix, a configuration, a metric, a model kind, a
+driver or a graph generator by adding files; nothing here names one.
 """
 from __future__ import annotations
 
+import functools
 import importlib.util
 import json
 from pathlib import Path
 
 BENCH = Path(__file__).resolve().parents[1]
 ROOT = BENCH.parent
+METRICS = BENCH / "metrics"
+KINDS = BENCH / "kinds"
+DRIVERS = BENCH / "drivers"
+GENERATORS = BENCH / "generators"
 
 
 def load_json(kind: str, name: str) -> dict:
@@ -54,10 +68,34 @@ def cell_metrics(bench: dict, cell_name: str) -> tuple[list, list]:
     return e2e, layer
 
 
-def metric_reader(name: str):
-    path = BENCH / "metrics" / f"{name}.py"
+@functools.cache
+def _module(path: Path):
+    """The Python file at ``path``, run once per process."""
+    if not path.is_file():
+        raise ValueError(f"no {path.parent.name} file named {path.stem!r}")
+    stem = path.stem.replace(".", "_").replace("-", "_")
     spec = importlib.util.spec_from_file_location(
-        "bench_metric_" + name.replace(".", "_").replace("-", "_"), path)
+        f"bench_{path.parent.name}_{stem}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def metric_reader(name: str):
+    return _module(METRICS / f"{name}.py").read
+
+
+def kind(name: str):
+    """The model kind's module: ``weights``, ``layer``, ``flops``,
+    ``config_fields``."""
+    return _module(KINDS / f"{name}.py")
+
+
+def driver(name: str):
+    """The driver's module: ``Driver``, ``compared_rows``."""
+    return _module(DRIVERS / f"{name}.py")
+
+
+def generator(name: str):
+    """``generate(n, links, seed, area) -> (edges, coords)``."""
+    return _module(GENERATORS / f"{name}.py").generate

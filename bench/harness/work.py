@@ -7,12 +7,12 @@ the vertices.
 
 Aggregation of one layer at width ``d`` (the neighbour sum): ``arcs * d``
 additions; bytes are every table row read once, every output row written
-once, and the two int32 indices of each arc.  Dense layers: ``2 n d_in
-d_out`` (``2 d_in`` for SAGE's concatenation).  GCN also adds its own row
-and divides by the degree (``2 n d_in``); SAGE divides by the degree
-(``n d_in``).
+once, and the two int32 indices of each arc.  A whole forward's count is
+the model kind's (``bench/kinds/<kind>.py``, ``flops``).
 """
 from __future__ import annotations
+
+from harness import registry
 
 F32 = 4
 INDEX = 4
@@ -25,18 +25,10 @@ def aggregation(n: int, arcs: int, d: int) -> tuple[int, int]:
     return flops, nbytes
 
 
-def model_flops(kind: str, layer_dims, n: int, arcs: int) -> int:
-    """Operations of one whole-graph forward."""
-    total = 0
-    for d_in, d_out in zip(layer_dims[:-1], layer_dims[1:]):
-        total += arcs * d_in
-        if kind == "gcn":
-            total += 2 * n * d_in + 2 * n * d_in * d_out
-        elif kind == "sage":
-            total += n * d_in + 2 * n * (2 * d_in) * d_out
-        else:
-            raise ValueError(kind)
-    return total
+def model_flops(model: dict, n: int, arcs: int) -> int:
+    """Operations of one whole-graph forward of the configuration's
+    ``model``, counted by its kind."""
+    return registry.kind(model["kind"]).flops(model, n, arcs)
 
 
 def aggregation_per_forward(layer_dims, n: int, arcs: int) -> tuple[int, int]:

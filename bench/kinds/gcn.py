@@ -1,0 +1,41 @@
+"""GCN (arXiv 2210.17281, Eq. 1), the model kind ``gcn``:
+
+  h_v' = sigma(W . (sum_{u in N_v} h_u + h_v) / (|N_v| + 1))
+
+sigma is ReLU on hidden layers and the identity on the last.  One weight
+``w`` of ``(d_in, d_out)`` a layer, as the program's ``init_params`` lays it
+out.
+"""
+import jax
+import jax.numpy as jnp
+
+
+def weights(model: dict) -> list:
+    """Per layer, ``{name: (shape, fan_in, fan_out)}`` in drawing order."""
+    dims = model["layer_dims"]
+    return [{"w": ((d_in, d_out), d_in, d_out)}
+            for d_in, d_out in zip(dims[:-1], dims[1:])]
+
+
+def layer(model: dict, k: int, p: dict, h, g, dt, prec):
+    """Layer ``k`` of the reference over the whole graph ``g`` (``src``,
+    ``dst``, in-degree ``deg``, ``n``), every array in ``dt``, the product
+    with the weight at ``prec``."""
+    agg = jax.ops.segment_sum(h[g.src], g.dst, num_segments=g.n)
+    z = (agg + h) / (g.deg + 1)[:, None]
+    out = jnp.dot(z, p["w"].astype(dt), precision=prec,
+                  preferred_element_type=dt)
+    return out if k == len(model["layer_dims"]) - 2 else jnp.maximum(out, 0)
+
+
+def flops(model: dict, n: int, arcs: int) -> int:
+    """One whole-graph forward: per layer the neighbour adds, the own row's
+    add and the degree's divide, and the product with ``w``."""
+    dims = model["layer_dims"]
+    return sum(arcs * d_in + 2 * n * d_in + 2 * n * d_in * d_out
+               for d_in, d_out in zip(dims[:-1], dims[1:]))
+
+
+def config_fields(model: dict) -> dict:
+    """Keyword fields of the program's ``GNNConfig`` beyond kind and widths."""
+    return {}

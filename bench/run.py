@@ -26,12 +26,9 @@ T_START = time.perf_counter()
 
 import argparse
 import gc
-import importlib
 import json
 import math
-import shutil
 import sys
-import tempfile
 import types
 from pathlib import Path
 
@@ -45,7 +42,6 @@ import numpy as np  # noqa: E402
 from harness import check, registry  # noqa: E402
 
 CACHE_DIR = ROOT / ".bench_cache" / "jax"
-DRIVERS = {"ego": "harness.ego", "refresh": "harness.refresh"}
 
 
 class NoChip(RuntimeError):
@@ -102,7 +98,7 @@ def main(argv=None) -> int:
         print(f"bench: {e}", file=sys.stderr)
         return 1
     clock = CompileClock()
-    module = importlib.import_module(DRIVERS[cell["params"]["driver"]])
+    module = registry.driver(cell["params"]["driver"])
     driver = module.Driver(cell, args.seed, args.seconds, devices)
     mark = jax.jit(tr.bench_window_mark)
     mark_arg = jax.device_put(np.zeros(8, np.float32), devices[0])
@@ -117,22 +113,17 @@ def main(argv=None) -> int:
     gc.collect()
     gc.freeze()
     before = clock.compiles
-    trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if args.trace else None
-    spans = [] if trace_dir else None
-    if trace_dir:
-        # Device operations only: Python function tracing slows the host
-        # loop several fold, and even the host tracer's lowest level
-        # doubles the ego cell's time per tick.
-        opts = jax.profiler.ProfileOptions()
-        opts.python_tracer_level = 0
-        opts.host_tracer_level = 0
-        jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    spans = [] if args.trace else None
+    if args.trace:
+        session = tr.start_profiler()
         mark_ns = time.perf_counter_ns()
         jax.block_until_ready(mark(mark_arg))
     values = driver.window(args.seconds, spans)
-    if trace_dir:
+    if args.trace:
         jax.block_until_ready(mark(mark_arg))
-        jax.profiler.stop_trace()
+        t_stop = time.perf_counter()
+        profile = session.stop_and_get_profile_data()
+        t_stop = time.perf_counter() - t_stop
     print(f"compiles in window: {clock.compiles - before}", file=sys.stderr)
     print(f"window: {values} {driver.counters()}", file=sys.stderr)
     peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
@@ -142,11 +133,11 @@ def main(argv=None) -> int:
               "memory_peak_bytes": int(peak)}
     values["setup_s"] = setup_s
     result_extra = {}
-    if trace_dir:
-        try:
-            trace = tr.load(trace_dir, len(devices), spans, mark_ns)
-        finally:
-            shutil.rmtree(trace_dir, ignore_errors=True)
+    if args.trace:
+        t_reduce = time.perf_counter()
+        trace = tr.load(profile, len(devices), spans, mark_ns)
+        profile = None
+        t_load = time.perf_counter() - t_reduce
         run = types.SimpleNamespace(
             config=cell["config"], device_kind=devices[0].device_kind,
             chips=len(devices), trace=trace, counters=driver.counters(),
@@ -161,6 +152,10 @@ def main(argv=None) -> int:
         device["window_s"] = trace.window_s
         result_extra["breakdown"] = {"device_ops": tr.top_ops(trace),
                                      "idle_gaps": tr.idle_gaps(trace)}
+        print(f"trace: {sum(len(e) for e in trace.ops + trace.async_ops)} "
+              f"operations, profiler stopped in {t_stop:.3f} s, loaded in "
+              f"{t_load:.3f} s, {time.perf_counter() - t_reduce:.3f} s "
+              f"with every reader", file=sys.stderr)
     else:
         metrics = {m["name"]: {"value": values[m["name"]], "unit": m["unit"]}
                    for m in e2e}

@@ -30,7 +30,8 @@ from harness import registry  # noqa: E402
 
 def main(argv=None) -> int:
     import run
-    from harness import ego
+
+    ego = registry.driver("ego")
 
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", required=True)

@@ -1,4 +1,5 @@
-"""The reader of the BSP forward's program time, on constructed runs."""
+"""The readers of the BSP forward's program time and of the exposed halo
+exchange, on constructed runs."""
 import sys
 import types
 from pathlib import Path
@@ -29,3 +30,25 @@ def test_bsp_fwd_device_ms_is_the_program_time_per_refresh_and_chip():
     assert read(_run(2, 0, mods)) is None
     # a program under another name (an older build) reads nothing
     assert read(_run(2, 2, [mods[0][1:2]] * 2)) is None
+
+
+def test_exchange_exposed_ms_is_the_bare_exchange_per_refresh_and_chip():
+    read = registry.metric_reader("exchange_exposed_ms")
+    # device 0: a permute [0, 4] ms under a fusion [0, 1] ms: 3 ms bare;
+    # device 1: an asynchronous permute [2, 6] ms, a fusion [5, 8] ms: 3 ms
+    ops = [[("collective-permute.1", 0, 4 * MS), ("fusion.2", 0, MS)],
+           [("fusion.3", 5 * MS, 8 * MS)]]
+    aops = [[], [("collective-permute-start.4", 2 * MS, 6 * MS)]]
+    tr = trace.Trace(ops=ops, modules=[[]] * 2, async_ops=aops, host=[],
+                     window=(0, 10 ** 9))
+    run = types.SimpleNamespace(trace=tr, chips=2,
+                                counters={"refreshes": 3})
+    # (3 + 3) / 2 chips = 3 ms over 3 refreshes
+    assert read(run) == pytest.approx(1.0)
+    run.counters["refreshes"] = 0
+    assert read(run) is None
+    # a trace with no exchange (one chip) reads nothing, not 0
+    one = trace.Trace(ops=[ops[0][1:]], modules=[[]], async_ops=[[]],
+                      host=[], window=(0, 10 ** 9))
+    assert read(types.SimpleNamespace(trace=one, chips=1,
+                                      counters={"refreshes": 3})) is None

@@ -1,5 +1,6 @@
-"""Every part of the benchmark is a file that the harness finds by name,
-and ``BENCHMARK.json`` agrees with those files."""
+"""Every part of the benchmark is a file that the harness finds by name
+(cells, mixes, configurations, metrics, model kinds, drivers, graph
+generators), and ``BENCHMARK.json`` agrees with those files."""
 import json
 import re
 import sys
@@ -39,7 +40,8 @@ def test_cell_loads_by_name(name):
     assert cell["chips"] == entry["chips"]
     assert cell["config_name"] == entry["config"]
     assert cell["traffic"] == entry["traffic"]
-    assert cell["params"]["driver"] in ("ego", "refresh")
+    driver = registry.driver(cell["params"]["driver"])
+    assert callable(driver.Driver) and callable(driver.compared_rows)
     assert set(cell["limits"]) <= {"max_err", "mean_err"} and cell["limits"]
     e2e, layer = registry.cell_metrics(BENCH, name)
     names = {m["name"] for m in e2e}
@@ -49,6 +51,24 @@ def test_cell_loads_by_name(name):
 @pytest.mark.parametrize("name", [m["name"] for m in BENCH["per_layer"]])
 def test_metric_reader_loads_by_name(name):
     assert callable(registry.metric_reader(name))
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
+def test_config_kind_and_generator_load_by_name(name):
+    cfg = registry.load_json("configs", name)
+    kind = registry.kind(cfg["model"]["kind"])
+    for part in ("weights", "layer", "flops", "config_fields"):
+        assert callable(getattr(kind, part))
+    assert callable(registry.generator(cfg["graph"]["generator"]))
+
+
+@pytest.mark.parametrize("loader", [registry.kind, registry.driver,
+                                    registry.generator,
+                                    registry.metric_reader],
+                         ids=lambda f: f.__name__)
+def test_an_unknown_name_is_refused(loader):
+    with pytest.raises(ValueError, match="no_such_part"):
+        loader("no_such_part")
 
 
 @pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])

@@ -9,7 +9,7 @@ import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[2] / "bench"))
 
-from harness import ego, graphs, peaks, work  # noqa: E402
+from harness import graphs, peaks, registry, work  # noqa: E402
 
 # 0-1, 1-2, 2-3, 1-3: n = 4, 4 links, 8 directed arcs
 EDGES = np.array([[0, 1], [1, 2], [2, 3], [1, 3]])
@@ -31,7 +31,9 @@ def test_aggregation_counts_adds_rows_and_indices():
      + (8 * 2 + 4 * 2 + 2 * 4 * 4 * 1)),
 ])
 def test_model_flops_by_hand(kind, expect):
-    assert work.model_flops(kind, (3, 2, 1), N, ARCS) == expect
+    model = {"kind": kind, "layer_dims": (3, 2, 1)}
+    assert work.model_flops(model, N, ARCS) == expect
+    assert registry.kind(kind).flops(model, N, ARCS) == expect
 
 
 def test_aggregation_per_forward_sums_hidden_widths():
@@ -41,8 +43,10 @@ def test_aggregation_per_forward_sums_hidden_widths():
 
 
 def test_siot_refresh_is_about_19_6_mflop():
-    f = work.model_flops("gcn", (52, 16, 2), 8001, 2 * 33509)
+    f = work.model_flops({"kind": "gcn", "layer_dims": (52, 16, 2)}, 8001,
+                         2 * 33509)
     assert 19.0e6 < f < 20.0e6
+    assert f == 19_471_088          # the count every earlier run was read by
 
 
 def test_least_time_names_its_bound():
@@ -77,7 +81,8 @@ def test_warmup_grid_holds_every_batch_the_program_pads():
     nodes, arcs = graphs.ego_sizes(n, edges)
     rng = np.random.default_rng(0)
     pool = rng.choice(n, size=60, replace=False)
-    grid = set(ego.bucket_grid(nodes[pool], arcs[pool], 16))
+    bucket_grid = registry.driver("ego").bucket_grid
+    grid = set(bucket_grid(nodes[pool], arcs[pool], 16))
     for _ in range(40):
         take = rng.choice(pool, size=int(rng.integers(1, 17)))
         b = extract_ego_batch(g, take, 2, None, batch=16)
