@@ -39,7 +39,9 @@ def build(config: dict, seed: int, device) -> Deployment:
 
 def layout(config: dict, dep: Deployment, parts: int):
     """(EdgeNetwork, ShardPlan).  ``parts == 1`` keeps every vertex on one
-    partition, as a single server holds the whole graph."""
+    partition, as a single server holds the whole graph.  Otherwise GLAD-S
+    costs the configuration's own ``layer_dims``, with the per-term scales
+    of the kind's entry in the program's cost model (``workload_for``)."""
     from repro.core import CostModel, workload_for
     from repro.core.glad_s import glad_s
     from repro.core.partition import partition_from_assign
@@ -56,8 +58,10 @@ def layout(config: dict, dep: Deployment, parts: int):
         if parts != int(fl["servers"]):
             raise ValueError(f"{parts} partitions over "
                              f"{fl['servers']} servers")
-        cm = CostModel(net, g, workload_for(config["model"]["kind"],
-                                            g.features.shape[1]))
+        m = config["model"]
+        dims = list(m["layer_dims"])
+        scales = workload_for(m["kind"], dims[0])
+        cm = CostModel(net, g, dataclasses.replace(scales, layer_dims=dims))
         res = glad_s(cm, seed=seed)
         part = partition_from_assign(g, res.assign, parts, res.factors)
     return net, compile_plan(g, part, slack=float(fl["slack"]))

@@ -52,16 +52,24 @@ def directed(edges: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
 
 def ego_sizes(n: int, edges: np.ndarray, hops: int = 2):
     """Per vertex: nodes within ``hops`` and the arcs an exact ``hops``-layer
-    ego forward aggregates (every incoming arc of each node closer than
-    ``hops``).  Used to size the warm-up; only ``hops == 2`` is needed."""
+    ego forward aggregates (every incoming arc of each distinct node closer
+    than ``hops``).  Used to size the warm-up.
+
+    The ball within ``k`` hops is the pattern of ``(I + A)^k``, kept as 0/1
+    after every product so that a node reached by many walks counts once
+    (``A @ A @ deg`` would count walks)."""
     import scipy.sparse as sp
 
-    if hops != 2:
-        raise ValueError("ego_sizes covers 2-hop egos")
+    if hops < 1:
+        raise ValueError(f"ego_sizes needs hops >= 1, got {hops}")
     src, dst = directed(edges)
     a = sp.csr_matrix((np.ones(len(src), np.int32), (src, dst)), shape=(n, n))
     deg = np.asarray(a.sum(axis=1)).ravel().astype(np.int64)
-    ball = (a + a @ a + sp.identity(n, dtype=np.int32, format="csr"))
+    ball = sp.identity(n, dtype=np.int32, format="csr")
+    for _ in range(hops):
+        inner = ball
+        ball = ball + ball @ a
+        ball.data[:] = 1
     nodes = np.diff(ball.indptr).astype(np.int64)
-    arcs = deg + a @ deg
+    arcs = inner @ deg
     return nodes, arcs

@@ -7,8 +7,9 @@ mix, cell, per-layer metric, model kind, driver and graph generator.
                                  parameters (rate, limits)
   bench/metrics/<metric>.py      ``read(run) -> float | None``
   bench/kinds/<kind>.py          a configuration's ``model.kind``: its weight
-                                 layout, reference layer, operation count and
-                                 the program's ``GNNConfig`` fields
+                                 layout, reference layer, operation and byte
+                                 counts and the program's ``GNNConfig``
+                                 fields
   bench/drivers/<driver>.py      a mix's ``driver``: ``Driver(cell, seed,
                                  seconds, devices)`` that builds and drives
                                  the system under test, and
@@ -87,7 +88,7 @@ def metric_reader(name: str):
 
 def kind(name: str):
     """The model kind's module: ``weights``, ``layer``, ``flops``,
-    ``config_fields``."""
+    ``bytes``, ``config_fields``."""
     return _module(KINDS / f"{name}.py")
 
 

@@ -5,24 +5,16 @@ retiles or replaces the aggregation kernel changes its time, not these
 counts.  ``arcs`` is the number of directed arcs (twice the links); ``n``
 the vertices.
 
-Aggregation of one layer at width ``d`` (the neighbour sum): ``arcs * d``
-additions; bytes are every table row read once, every output row written
-once, and the two int32 indices of each arc.  A whole forward's count is
-the model kind's (``bench/kinds/<kind>.py``, ``flops``).
+A whole forward's counts are the model kind's (``bench/kinds/<kind>.py``,
+``flops`` and ``bytes``).  Its bytes are the least the forward moves: per
+layer each input row, output row and weight once, the in-degree where the
+layer reads it, and each arc's two int32 indices (``INDEX`` bytes each).
 """
 from __future__ import annotations
 
 from harness import registry
 
-F32 = 4
-INDEX = 4
-
-
-def aggregation(n: int, arcs: int, d: int) -> tuple[int, int]:
-    """(flops, bytes) of one neighbour sum over the whole graph."""
-    flops = arcs * d
-    nbytes = 2 * n * d * F32 + 2 * arcs * INDEX
-    return flops, nbytes
+INDEX = 4       # bytes of an int32 arc endpoint
 
 
 def model_flops(model: dict, n: int, arcs: int) -> int:
@@ -31,10 +23,8 @@ def model_flops(model: dict, n: int, arcs: int) -> int:
     return registry.kind(model["kind"]).flops(model, n, arcs)
 
 
-def aggregation_per_forward(layer_dims, n: int, arcs: int) -> tuple[int, int]:
-    """(flops, bytes) of every layer's neighbour sum in one forward."""
-    flops = nbytes = 0
-    for d in layer_dims[:-1]:
-        f, b = aggregation(n, arcs, d)
-        flops, nbytes = flops + f, nbytes + b
-    return flops, nbytes
+def model_bytes(model: dict, n: int, arcs: int) -> int:
+    """Bytes one whole-graph forward of the configuration's ``model`` has
+    to move at the least, counted by its kind."""
+    return registry.kind(model["kind"]).bytes(model, n, arcs)
+

@@ -10,6 +10,8 @@ d_out)`` a layer, as the program's ``init_params`` lays it out.
 import jax
 import jax.numpy as jnp
 
+from harness.work import INDEX
+
 
 def weights(model: dict) -> list:
     """Per layer, ``{name: (shape, fan_in, fan_out)}`` in drawing order."""
@@ -34,6 +36,16 @@ def flops(model: dict, n: int, arcs: int) -> int:
     divide and the product with the ``2 d_in``-wide ``w``."""
     dims = model["layer_dims"]
     return sum(arcs * d_in + n * d_in + 2 * n * (2 * d_in) * d_out
+               for d_in, d_out in zip(dims[:-1], dims[1:]))
+
+
+def bytes(model: dict, n: int, arcs: int) -> int:
+    """The least one whole-graph forward moves: per layer every input row,
+    output row and the ``2 d_in``-wide weight once, the in-degree, and each
+    arc's two int32 indices."""
+    dims, f = model["layer_dims"], jnp.dtype(model["dtype"]).itemsize
+    return sum(f * (n * d_in + n * d_out + 2 * d_in * d_out + n)
+               + 2 * arcs * INDEX
                for d_in, d_out in zip(dims[:-1], dims[1:]))
 
 

@@ -1,5 +1,5 @@
-"""The readers of the BSP forward's program time and of the exposed halo
-exchange, on constructed runs."""
+"""The readers of the BSP forward's program time, its HBM roofline share
+and the exposed halo exchange, on constructed runs."""
 import sys
 import types
 from pathlib import Path
@@ -30,6 +30,26 @@ def test_bsp_fwd_device_ms_is_the_program_time_per_refresh_and_chip():
     assert read(_run(2, 0, mods)) is None
     # a program under another name (an older build) reads nothing
     assert read(_run(2, 2, [mods[0][1:2]] * 2)) is None
+
+
+def test_refresh_hbm_share_is_the_least_bytes_over_program_time_at_peak():
+    read = registry.metric_reader("refresh_hbm_share")
+    mods = [[("jit_bsp_forward(3)", 0, MS), ("jit_inner(3)", 0, 10 * MS),
+             ("jit_bsp_forward(3)", 2 * MS, 3 * MS)],
+            [("jit_bsp_forward(3)", 0, 3 * MS)]]
+    run = _run(2, 2, mods)
+    run.config = {"model": {"kind": "gcn", "layer_dims": [3, 2, 1],
+                            "dtype": "float32"}}
+    run.n, run.arcs, run.device_kind = 4, 8, "TPU v5 lite"
+    # 320 bytes a forward (test_bench_work's hand count), 2 refreshes,
+    # 5 ms of the program summed over both chips, at 819 GB/s
+    assert read(run) == pytest.approx(100.0 * 320 * 2 / (5e-3 * 819e9))
+    run.counters["refreshes"] = 0
+    assert read(run) is None
+    # no program time traced: nothing, not 0
+    run.counters["refreshes"] = 2
+    run.trace = _run(2, 2, [mods[0][1:2]] * 2).trace
+    assert read(run) is None
 
 
 def test_exchange_exposed_ms_is_the_bare_exchange_per_refresh_and_chip():

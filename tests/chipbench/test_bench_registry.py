@@ -57,7 +57,7 @@ def test_metric_reader_loads_by_name(name):
 def test_config_kind_and_generator_load_by_name(name):
     cfg = registry.load_json("configs", name)
     kind = registry.kind(cfg["model"]["kind"])
-    for part in ("weights", "layer", "flops", "config_fields"):
+    for part in ("weights", "layer", "flops", "bytes", "config_fields"):
         assert callable(getattr(kind, part))
     assert callable(registry.generator(cfg["graph"]["generator"]))
 

@@ -1,5 +1,6 @@
 """The benchmark's useful-work counters and ego sizing against hand counts
-on a 4-vertex graph, and its warm-up grid against the program's own
+on a 4-vertex graph, ego sizing against the program's extraction and the
+parent's 2-hop formula, and its warm-up grid against the program's own
 batching."""
 import sys
 from pathlib import Path
@@ -16,12 +17,6 @@ EDGES = np.array([[0, 1], [1, 2], [2, 3], [1, 3]])
 N, ARCS = 4, 8
 
 
-def test_aggregation_counts_adds_rows_and_indices():
-    flops, nbytes = work.aggregation(N, ARCS, 3)
-    assert flops == 8 * 3                              # one add per arc and lane
-    assert nbytes == (4 * 3 + 4 * 3) * 4 + 8 * 2 * 4   # rows in, rows out, arcs
-
-
 @pytest.mark.parametrize("kind,expect", [
     # layer 3->2, then 2->1: neighbour adds, self add + degree divide,
     # matmul (SAGE: its mean's divide and a 2*d_in-wide matmul)
@@ -36,10 +31,32 @@ def test_model_flops_by_hand(kind, expect):
     assert registry.kind(kind).flops(model, N, ARCS) == expect
 
 
-def test_aggregation_per_forward_sums_hidden_widths():
-    f, b = work.aggregation_per_forward((3, 2, 1), N, ARCS)
-    assert f == 8 * 3 + 8 * 2
-    assert b == work.aggregation(N, ARCS, 3)[1] + work.aggregation(N, ARCS, 2)[1]
+@pytest.mark.parametrize("kind,expect", [
+    # float32: per layer rows in, rows out, the weight, the in-degree, and
+    # two int32 indices per arc; layer 3->2, then 2->1 (SAGE: 2*d_in-wide w)
+    ("gcn", 4 * (4 * 3 + 4 * 2 + 3 * 2 + 4) + 8 * 2 * 4
+     + 4 * (4 * 2 + 4 * 1 + 2 * 1 + 4) + 8 * 2 * 4),
+    ("sage", 4 * (4 * 3 + 4 * 2 + 6 * 2 + 4) + 8 * 2 * 4
+     + 4 * (4 * 2 + 4 * 1 + 4 * 1 + 4) + 8 * 2 * 4),
+])
+def test_model_bytes_by_hand(kind, expect):
+    model = {"kind": kind, "layer_dims": (3, 2, 1), "dtype": "float32"}
+    assert work.model_bytes(model, N, ARCS) == expect
+    assert registry.kind(kind).bytes(model, N, ARCS) == expect
+
+
+@pytest.mark.parametrize("config,expect", [
+    # float32, n = 8001, 2 * 33509 arcs: 52->16, 16->2
+    ("siot-gcn", 4 * (8001 * 52 + 8001 * 16 + 52 * 16 + 8001) + 8 * 67018
+     + 4 * (8001 * 16 + 8001 * 2 + 16 * 2 + 8001) + 8 * 67018),
+    # float32, n = 3912, 2 * 4677 arcs: 100->16, 16->2, SAGE's 2*d_in-wide w
+    ("yelp-sage", 4 * (3912 * 100 + 3912 * 16 + 200 * 16 + 3912) + 8 * 9354
+     + 4 * (3912 * 16 + 3912 * 2 + 32 * 2 + 3912) + 8 * 9354),
+])
+def test_configured_forward_bytes_by_hand(config, expect):
+    cfg = registry.load_json("configs", config)
+    n, links = cfg["graph"]["n"], cfg["graph"]["links"]
+    assert work.model_bytes(cfg["model"], n, 2 * links) == expect
 
 
 def test_siot_refresh_is_about_19_6_mflop():
@@ -69,6 +86,45 @@ def test_ego_sizes_by_hand_and_against_the_program():
     for v in range(N):
         nd, ac, _ = extract_ego(g, v, 2)
         assert (nodes[v], arcs[v]) == (len(nd), len(ac))
+
+
+def _parent_ego_sizes(n, edges):
+    # graphs.ego_sizes as it was when it covered 2-hop egos alone
+    import scipy.sparse as sp
+
+    src, dst = graphs.directed(edges)
+    a = sp.csr_matrix((np.ones(len(src), np.int32), (src, dst)), shape=(n, n))
+    deg = np.asarray(a.sum(axis=1)).ravel().astype(np.int64)
+    ball = (a + a @ a + sp.identity(n, dtype=np.int32, format="csr"))
+    return np.diff(ball.indptr).astype(np.int64), deg + a @ deg
+
+
+@pytest.mark.parametrize("config", ["siot-gcn", "yelp-sage"])
+def test_two_hop_ego_sizes_are_the_parents(config):
+    n, edges, _ = graphs.build(registry.load_json("configs", config)["graph"])
+    for got, want in zip(graphs.ego_sizes(n, edges, 2),
+                         _parent_ego_sizes(n, edges)):
+        assert got.dtype == want.dtype and np.array_equal(got, want)
+
+
+@pytest.mark.parametrize("hops", [1, 2, 3])
+def test_ego_sizes_count_what_the_program_extracts(hops):
+    from repro.graphs.datagraph import DataGraph
+    from repro.gnn import extract_ego
+
+    rng = np.random.default_rng(11)
+    n = 120
+    edges = graphs.canonical(rng.integers(0, n, size=(260, 2)), n)
+    nodes, arcs = graphs.ego_sizes(n, edges, hops)
+    g = DataGraph(n=n, edges=edges)
+    for v in range(n):
+        nd, ac, _ = extract_ego(g, v, hops)
+        assert (nodes[v], arcs[v]) == (len(nd), len(ac)), v
+
+
+def test_ego_sizes_refuse_no_hops():
+    with pytest.raises(ValueError, match="hops"):
+        graphs.ego_sizes(N, EDGES, 0)
 
 
 def test_warmup_grid_holds_every_batch_the_program_pads():
